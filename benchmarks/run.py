@@ -28,6 +28,8 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     common.add_plan_args(ap)
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     common.configure_from_args(args)
 
     print(common.CSV_HEADER)

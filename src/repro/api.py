@@ -232,16 +232,13 @@ class MatmulPolicy:
         """The ``core.ozaki.OzakiConfig`` this policy resolves to.
 
         Shape-only (k sizes the auto split count), so the result is
-        trace-stable. ``interpret`` defaults from the host
-        (``kernels.ops.INTERPRET``: interpret-mode Pallas on CPU
-        validation hosts, Mosaic lowering on TPU).
+        trace-stable. ``interpret=None`` takes the host default
+        (``kernels.ops.INTERPRET``: interpret-mode Pallas off a TPU,
+        Mosaic lowering on one) when the plan is built.
         """
         if self.scheme != "ozaki_fp64":
             raise ValueError(f"scheme {self.scheme!r} has no OzakiConfig")
         from repro.core.ozaki import OzakiConfig
-        if interpret is None:
-            from repro.kernels.ops import INTERPRET
-            interpret = INTERPRET
         return OzakiConfig(
             num_splits=self.resolve_num_splits(k), accum=accum,
             backend=self.backend, fuse_epilogue=self.fuse_epilogue,
@@ -258,9 +255,6 @@ class MatmulPolicy:
         if self.scheme != "ozaki2_fp64":
             raise ValueError(f"scheme {self.scheme!r} has no ModularConfig")
         from repro.core.modular import ModularConfig
-        if interpret is None:
-            from repro.kernels.ops import INTERPRET
-            interpret = INTERPRET
         return ModularConfig(num_moduli=self.num_splits,
                              target_error=self.target_error,
                              backend=self.backend,
@@ -503,7 +497,9 @@ def matmul(a, b, precision=None):
     Dispatch (ozaki scheme) on rank/dtype/DW-ness, to the
     bitwise-verified pipelines:
 
-    * ``DW`` operands          -> the TPU-native df32 entry (df32 out).
+    * ``DW`` operands          -> the TPU-native df32 entry (df32 out);
+                                  a 3-D ``a`` with 2-D weights folds
+                                  its batch into rows.
     * complex 2-D              -> the 4-mul complex pipeline.
     * 3-D ``a``                -> the batched pipeline; ``b`` may be 3-D
                                   (stacked weights, batch-grid kernel) or
@@ -645,6 +641,17 @@ def _matmul_ozaki_dispatch(a, b, pol: MatmulPolicy):
     if isinstance(a, DW) or isinstance(b, DW):
         if not (isinstance(a, DW) and isinstance(b, DW)):
             raise TypeError("DW matmul needs both operands as DW")
+        if a.hi.ndim == 3 and b.hi.ndim == 2:
+            # broadcast weights: fold the batch into rows — exact, the
+            # same fold the f32/f64 batched pipeline makes
+            bsz, m, k = a.hi.shape
+            out = _matmul_ozaki_dispatch(
+                DW(a.hi.reshape(bsz * m, k), a.lo.reshape(bsz * m, k)), b,
+                pol)
+            return DW(out.hi.reshape(bsz, m, -1), out.lo.reshape(bsz, m, -1))
+        if a.hi.ndim != 2 or b.hi.ndim != 2:
+            raise ValueError(f"DW operands must be 2-D, or 3-D with 2-D "
+                             f"weights, got {a.hi.shape} @ {b.hi.shape}")
         k = a.hi.shape[-1]
         cfg = pol.ozaki_config(k, accum="df32")
         b_t = DW(b.hi.T, b.lo.T)               # exact: a permutation

@@ -63,6 +63,7 @@ import numpy as np
 from .modular import modular_eta, resolve_modular
 from .splitting import row_exponents, slice_width
 from .tuning import diagonal_groups, hbm_pass_model, parse_pair_policy
+from .xmath import exponent
 
 __all__ = ["MAX_SPLITS", "SchemeChoice", "kept_pairs", "truncation_eta",
            "input_truncation_eta", "accum_floor", "error_bound",
@@ -394,7 +395,7 @@ def exponent_spread(m) -> jnp.ndarray:
     """
     m = jnp.asarray(m)
     row_e = row_exponents(m)
-    _, e = jnp.frexp(m)
+    e = exponent(m)
     e = jnp.where(m != 0, e.astype(jnp.int32), row_e[:, None])
     return row_e - jnp.min(e, axis=-1).astype(jnp.int32)
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .splitting import row_exponents
+from .xmath import exponent
 
 
 @functools.partial(jax.jit, static_argnames=("w", "mantissa_bits", "max_splits"))
@@ -31,7 +32,7 @@ def _loss_curve(m: jax.Array, w: int, mantissa_bits: int,
     clipped to [0, mantissa_bits]. Zeros lose nothing.
     """
     row_e = row_exponents(m)[:, None]
-    _, elem_e = jnp.frexp(m)
+    elem_e = exponent(m)
     nonzero = m != 0
     losses = []
     for s in range(1, max_splits + 1):

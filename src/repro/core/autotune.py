@@ -361,7 +361,7 @@ def candidate_plans(m: int, n: int, k: int, *, batch: int = 1,
                     streaming: bool = False,
                     shard_axis: Optional[str] = None,
                     comm: str = "f64",
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     search_num_splits: int = 0,
                     target_error: Optional[float] = None,
                     fast_mode: bool = False,
@@ -615,7 +615,7 @@ def autotune_plan(m: int, n: int, k: int, *, batch: int = 1,
                   fuse_epilogue: bool = True,
                   streaming: bool = False,
                   shard_axis: Optional[str] = None,
-                  comm: str = "f64", interpret: bool = True,
+                  comm: str = "f64", interpret: Optional[bool] = None,
                   target_error: Optional[float] = None,
                   fast_mode: bool = False,
                   pair_policy: Optional[str] = None,
@@ -639,6 +639,8 @@ def autotune_plan(m: int, n: int, k: int, *, batch: int = 1,
     immediately, so a crash after tuning N of M shapes keeps the N
     measured plans.
     """
+    from repro.kernels.launch import resolve_interpret
+    interpret = resolve_interpret(interpret)
     accuracy_pinned = (target_error is not None or fast_mode or
                        pair_policy is not None)
     if scheme == "ozaki2_fp64":

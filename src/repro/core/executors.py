@@ -45,7 +45,7 @@ from .modular import (center_mod, crt_digits, crt_value, garner_constants,
                       residues_from_slices, usable_moduli)
 from .splitting import SplitResult, row_exponents, split_int, split_int_dw
 from .tuning import BACKENDS, PipelinePlan
-from .xmath import DW, dw_add, dw_normalize
+from .xmath import DW, dw_add, dw_normalize, ldexp
 
 __all__ = ["BACKENDS", "XlaExecutor", "PallasExecutor", "FusedExecutor",
            "EpilogueExecutor", "StreamingExecutor", "StreamingSplit",
@@ -123,15 +123,15 @@ class XlaExecutor:
         if self.plan.accum == "f64":
             c = jnp.zeros(shape, jnp.float64)
             for t, p_t in _ordered(products):
-                c = c + jnp.ldexp(p_t.astype(jnp.float64),
-                                  e_base - (t + 2) * w)
+                c = c + ldexp(p_t.astype(jnp.float64),
+                              e_base - (t + 2) * w)
             return c
         acc = DW(jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
         for t, p_t in _ordered(products):
             scale = jnp.float32(2.0 ** (-(t + 2) * w))  # exact power of two
             term = int32_to_dw(p_t)
             acc = dw_add(acc, DW(term.hi * scale, term.lo * scale))
-        return DW(jnp.ldexp(acc.hi, e_base), jnp.ldexp(acc.lo, e_base))
+        return DW(ldexp(acc.hi, e_base), ldexp(acc.lo, e_base))
 
     # ---- stages 2+3 -----------------------------------------------------
     def contract(self, sa: SplitResult, sb: SplitResult, w: int,
@@ -199,14 +199,14 @@ class FusedExecutor(PallasExecutor):
             for t, p_t in _ordered(products):
                 c = accum_scaled_sw(fold2d(p_t), c,
                                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return jnp.ldexp(c.reshape(shape), e_base)
+            return ldexp(c.reshape(shape), e_base)
         c_hi = fold2d(jnp.zeros(shape, jnp.float32))
         c_lo = fold2d(jnp.zeros(shape, jnp.float32))
         for t, p_t in _ordered(products):
             c_hi, c_lo = accum_scaled_dw(fold2d(p_t), c_hi, c_lo,
                                          scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(jnp.ldexp(c_hi.reshape(shape), e_base),
-                  jnp.ldexp(c_lo.reshape(shape), e_base))
+        return DW(ldexp(c_hi.reshape(shape), e_base),
+                  ldexp(c_lo.reshape(shape), e_base))
 
 
 class EpilogueExecutor(FusedExecutor):
@@ -253,14 +253,14 @@ class EpilogueExecutor(FusedExecutor):
                 c = int8_matmul_nt_epilogue_sw(
                     sa.slices, sb.slices, c, p_lo=p_lo, t=t, npairs=npairs,
                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return jnp.ldexp(c, e_base)
+            return ldexp(c, e_base)
         c_hi = jnp.zeros(shape, jnp.float32)
         c_lo = jnp.zeros(shape, jnp.float32)
         for t, p_lo, npairs in self._groups():
             c_hi, c_lo = int8_matmul_nt_epilogue_dw(
                 sa.slices, sb.slices, c_hi, c_lo, p_lo=p_lo, t=t,
                 npairs=npairs, scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(jnp.ldexp(c_hi, e_base), jnp.ldexp(c_lo, e_base))
+        return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
 
 
 class StreamingSplit(NamedTuple):
@@ -315,14 +315,14 @@ class StreamingExecutor(EpilogueExecutor):
                 c = int8_matmul_nt_streaming_sw(
                     *a_ops, *b_ops, c, p_lo=p_lo, t=t, npairs=npairs,
                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return jnp.ldexp(c, e_base)
+            return ldexp(c, e_base)
         c_hi = jnp.zeros(shape, jnp.float32)
         c_lo = jnp.zeros(shape, jnp.float32)
         for t, p_lo, npairs in self._groups():
             c_hi, c_lo = int8_matmul_nt_streaming_dw(
                 *a_ops, *b_ops, c_hi, c_lo, p_lo=p_lo, t=t, npairs=npairs,
                 scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(jnp.ldexp(c_hi, e_base), jnp.ldexp(c_lo, e_base))
+        return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
 
 
 class ModularXlaExecutor:
@@ -424,7 +424,7 @@ class ModularEpilogueExecutor(ModularFusedExecutor):
         out = int8_matmul_nt_crt(ra, rb, moduli=mods, qmod=qmod, inv=inv,
                                  scales=scales, bm=tile.bm, bn=tile.bn,
                                  bk=tile.bk, interpret=self.plan.interpret)
-        return jnp.ldexp(out, e_base)
+        return ldexp(out, e_base)
 
 
 def get_executor(plan: PipelinePlan) -> XlaExecutor:

@@ -67,6 +67,7 @@ import numpy as np
 from .analytic import DGEMM_MANTISSA_SPACE
 from .splitting import SplitResult, split_int
 from .tuning import PipelinePlan, TilePlan
+from .xmath import ldexp, refuse_f64_on_tpu
 
 __all__ = ["MAX_BETA", "ModularConfig", "ModularPoint", "center_mod",
            "crt_digits", "crt_value", "crt_value_dw", "garner_constants",
@@ -371,7 +372,7 @@ def crt_value(digits: Sequence[jax.Array], moduli: Sequence[int], beta: int,
         t_lo = vf * lo                       # exact: 7 + 27 bits
         c = t_lo if c is None else c + t_lo  # smallest piece first
         c = c + vf * hi                      # exact: 7 + 26 bits
-    return jnp.ldexp(c, e_base)
+    return ldexp(c, e_base)
 
 
 def garner_constants(moduli: Sequence[int], beta: int):
@@ -412,7 +413,7 @@ def crt_value_dw(digits: Sequence[jax.Array], moduli: Sequence[int],
         scale_dw = DW(jnp.float32(hi), jnp.float32(lo))
         term = dw_mul_single(scale_dw, v.astype(jnp.float32))
         c = term if c is None else dw_add(c, term)
-    return DW(jnp.ldexp(c.hi, e_base), jnp.ldexp(c.lo, e_base))
+    return DW(ldexp(c.hi, e_base), ldexp(c.lo, e_base))
 
 
 # ----------------------------------------------------------------------------
@@ -440,7 +441,8 @@ class ModularConfig:
                   reconstruction inside the residue GEMM grid's epilogue
                   (VMEM scratch over the modulus axis) — int32 residue
                   products never round-trip through HBM.
-    interpret:    Pallas interpret mode (CPU validation hosts).
+    interpret:    Pallas interpret mode; None takes the host default
+                  (``kernels.ops.INTERPRET``: on off a TPU, off on one).
     tile:         optional TilePlan for the kernel launches.
     """
 
@@ -450,7 +452,7 @@ class ModularConfig:
     w: int = 7
     backend: str = "xla"
     fuse_epilogue: bool = False
-    interpret: bool = True
+    interpret: Optional[bool] = None
     tile: Optional[TilePlan] = None
 
     def point(self, k: int) -> ModularPoint:
@@ -467,7 +469,7 @@ class ModularConfig:
 
 def modular_plan(k: int, *, point: Optional[ModularPoint] = None,
                  backend: str = "xla", fuse_epilogue: bool = False,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  tile: Optional[TilePlan] = None,
                  batch_layout: str = "none",
                  target_error: Optional[float] = None,
@@ -517,6 +519,7 @@ def _check_f64(a, b, name: str) -> None:
             f"{name} takes float64 operands (complex128 routes through "
             f"ozaki2_matmul_complex, float32 through ozaki2_matmul_df32), "
             f"got {a.dtype} @ {b.dtype}")
+    refuse_f64_on_tpu(name)
 
 
 def ozaki2_matmul(a: jax.Array, b: jax.Array,
@@ -623,6 +626,7 @@ def ozaki2_matmul_complex(a: jax.Array, b: jax.Array,
     ``algo="3mul"``: Karatsuba, one fewer residue-GEMM group at one
     extra magnitude bit on the summed operands (covered by beta).
     """
+    refuse_f64_on_tpu("ozaki2_matmul_complex")
     if a.dtype != jnp.complex128 or b.dtype != jnp.complex128:
         raise TypeError(f"ozaki2_matmul_complex takes complex128 operands, "
                         f"got {a.dtype} @ {b.dtype}")
@@ -676,6 +680,8 @@ def ozaki2_matmul_df32(a: jax.Array, b: jax.Array,
     FP64 sum, so past the integer stages the route needs no FP64
     hardware. Returns ``dw_to_single`` of the DW result (f32).
     """
+    # the CRT sum is df32, but the split widens to f64 first
+    refuse_f64_on_tpu("ozaki2_matmul_df32")
     if a.dtype != jnp.float32 or b.dtype != jnp.float32:
         raise TypeError(f"ozaki2_matmul_df32 takes float32 operands, got "
                         f"{a.dtype} @ {b.dtype}")

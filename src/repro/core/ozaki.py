@@ -73,7 +73,7 @@ from .executors import StreamingSplit, get_executor, int32_to_dw
 from .splitting import SplitResult, slice_width
 from .tuning import (BACKENDS, PipelinePlan, TilePlan, diagonal_groups,
                      parse_pair_policy, plan_for)
-from .xmath import DW, dw_to_single
+from .xmath import DW, dw_to_single, refuse_f64_on_tpu
 
 @dataclasses.dataclass(frozen=True)
 class OzakiConfig:
@@ -119,7 +119,9 @@ class OzakiConfig:
         collective schedules). Result-invariant; ignored unless a shard
         axis and mesh are in play.
     ell_acc / ell_in: accumulator / input mantissa widths (Table 2).
-    interpret: run Pallas kernels in interpret mode (CPU validation).
+    interpret: run Pallas kernels in interpret mode; None takes the host
+        default (``kernels.ops.INTERPRET``: on off a TPU, off on one),
+        resolved when the plan is built.
     tile: optional TilePlan with per-stage block shapes (core.tuning).
     """
 
@@ -138,7 +140,7 @@ class OzakiConfig:
     comm: str = "f64"
     ell_acc: int = 31
     ell_in: int = 7
-    interpret: bool = True
+    interpret: Optional[bool] = None
     tile: Optional[TilePlan] = None
 
     def width_for(self, k: int) -> int:
@@ -247,6 +249,7 @@ def ozaki_matmul(a: jax.Array, b: jax.Array,
     if a.dtype != jnp.float64:
         raise TypeError("ozaki_matmul takes float64; use ozaki_matmul_dw for "
                         "the TPU df32 path")
+    refuse_f64_on_tpu("ozaki_matmul")
     k = a.shape[1]
     cfg = resolve_accuracy_config(cfg, k)
     w = cfg.width_for(k)
@@ -367,6 +370,8 @@ def ozaki_matmul_batched(a: jax.Array, b: jax.Array,
         raise ValueError(f"contraction mismatch: {a.shape} vs {b.shape}")
     if a.dtype != b.dtype:
         raise TypeError(f"dtype mismatch: {a.dtype} vs {b.dtype}")
+    if a.dtype == jnp.float64:
+        refuse_f64_on_tpu("ozaki_matmul_batched")
     return _batched_core(a, b, cfg)
 
 
@@ -384,6 +389,7 @@ def ozaki_matmul_complex(a: jax.Array, b: jax.Array,
     ``algo="3mul"``: Karatsuba, one fewer real GEMM group at slightly wider
     exponent range (beyond-paper option).
     """
+    refuse_f64_on_tpu("ozaki_matmul_complex")
     ar, ai = jnp.real(a), jnp.imag(a)
     br, bi = jnp.real(b), jnp.imag(b)
     k = a.shape[1]

@@ -31,7 +31,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .xmath import DW, fast_two_sum, two_sum
+from .xmath import DW, exponent, fast_two_sum, ldexp, two_sum
 
 INT8_MIN, INT8_MAX = -128, 127
 
@@ -96,7 +96,7 @@ def row_exponents(m: jax.Array) -> jax.Array:
     amax = jnp.max(jnp.abs(m), axis=-1)
     # frexp: x = mant * 2**e with mant in [0.5, 1)  ->  2**e >= |x|, strict
     # unless mant == 0.5 exactly (x a power of two), where 2**e == 2*x > x.
-    _, e = jnp.frexp(amax)
+    e = exponent(amax)
     return jnp.where(amax > 0, e, ZERO_ROW_EXP).astype(jnp.int32)
 
 
@@ -113,7 +113,7 @@ def split_int(m: jax.Array, num_splits: int, w: int,
     if exp is None:
         exp = row_exponents(m)
     sign = jnp.where(m < 0, -1, 1).astype(jnp.int8)
-    r = jnp.ldexp(jnp.abs(m), -exp[:, None]).astype(m.dtype)  # exact, [0, 1)
+    r = ldexp(jnp.abs(m), -exp[:, None]).astype(m.dtype)  # exact, [0, 1)
     scale = jnp.asarray(2.0 ** w, m.dtype)
 
     def body(r, _):
@@ -141,8 +141,8 @@ def split_int_dw(m: DW, num_splits: int, w: int) -> SplitResult:
     sign = jnp.where(neg, -1, 1).astype(jnp.int8)
     a_hi = jnp.where(neg, -m.hi, m.hi)
     a_lo = jnp.where(neg, -m.lo, m.lo)
-    r_hi = jnp.ldexp(a_hi, -exp[:, None]).astype(jnp.float32)
-    r_lo = jnp.ldexp(a_lo, -exp[:, None]).astype(jnp.float32)
+    r_hi = ldexp(a_hi, -exp[:, None]).astype(jnp.float32)
+    r_lo = ldexp(a_lo, -exp[:, None]).astype(jnp.float32)
     scale = jnp.float32(2.0 ** w)
 
     def body(carry, _):
@@ -167,8 +167,8 @@ def reconstruct(res: SplitResult, dtype=jnp.float64) -> jax.Array:
     s = res.slices.shape[0]
     out = jnp.zeros(res.slices.shape[1:], dtype)
     for p in range(s - 1, -1, -1):
-        term = jnp.ldexp(res.slices[p].astype(dtype),
-                         res.exp[:, None] - (p + 1) * res.w)
+        term = ldexp(res.slices[p].astype(dtype),
+                     res.exp[:, None] - (p + 1) * res.w)
         out = out + term
     return out
 

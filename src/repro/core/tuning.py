@@ -368,12 +368,15 @@ class PipelinePlan:
     concat_k: bool = False
     full_pairs: bool = False
     accum: str = "f64"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     scheme: str = "ozaki_fp64"
     beta: int = 0
     num_moduli: int = 0
 
     def __post_init__(self):
+        if self.interpret is None:
+            from repro.kernels.launch import resolve_interpret
+            object.__setattr__(self, "interpret", resolve_interpret(None))
         if self.backend not in BACKENDS:
             raise ValueError(f"unknown backend {self.backend!r}; "
                              f"expected one of {BACKENDS}")
@@ -523,7 +526,7 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
                          streaming: bool = False,
                          shard_axis: Optional[str] = None,
                          comm: str = "f64",
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          target_error: Optional[float] = None,
                          fast_mode: bool = False,
                          pair_policy: Optional[str] = None,
@@ -567,6 +570,8 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
     with the scheme, and fast-mode/pair-policy knobs are rejected (the
     residue path has no pair schedule).
     """
+    from repro.kernels.launch import resolve_interpret
+    interpret = resolve_interpret(interpret)
     if batch <= 1 and not broadcast_weights:
         layout = "none"
     elif broadcast_weights:

@@ -141,6 +141,114 @@ def dw_to_single(x: DW):
 
 
 # ----------------------------------------------------------------------------
+# Exact power-of-two scaling without 64-bit bitcasts
+# ----------------------------------------------------------------------------
+#
+# ``jnp.frexp``/``jnp.ldexp`` read the exponent field through a
+# ``bitcast_convert`` of the value to an integer of its own width. XLA:TPU
+# has no 64-bit bitcast, so on float64 they do not compile there. The two
+# helpers below give the same bits from comparisons, f32 conversions and
+# multiplications by exact powers of two; other dtypes go straight to jnp.
+
+def _pow2_f32(n):
+    """Exact ``2.0 ** n`` in f32 for int32 n in [-126, 127], from its
+    bits (a 32-bit bitcast, which every backend has)."""
+    return jax.lax.bitcast_convert_type((n + 127) << 23, jnp.float32)
+
+
+def _pow2_f64(e):
+    """Exact ``2.0 ** e`` in f64 for int32 ``e`` (0 below 2^-1074, inf
+    above 2^1023).
+
+    ``e = 4q + r`` with q truncated toward zero: ``2^q`` is a product of
+    three f32 powers, squared twice, then times ``2^r``. Every partial
+    product is a power of two between 1 and the result, so each multiply
+    is exact."""
+    e = jnp.clip(e, -1075, 1024)
+    q = jnp.where(e < 0, -((-e) >> 2), e >> 2)
+    r = e - 4 * q                                   # |r| <= 3
+    q1 = jnp.clip(q, -90, 90)                       # |q| <= 269
+    q2 = jnp.clip(q - q1, -90, 90)
+    p = (_pow2_f32(q1).astype(jnp.float64) *
+         _pow2_f32(q2).astype(jnp.float64) *
+         _pow2_f32(q - q1 - q2).astype(jnp.float64))
+    p = p * p
+    p = p * p
+    return p * _pow2_f32(r).astype(jnp.float64)
+
+
+@jax.jit
+def exponent(x):
+    """``jnp.frexp(x)[1]`` bitwise (int32): ``2^(e-1) <= |x| < 2^e`` for
+    finite nonzero x, 0 for zeros, infinities and NaNs.
+
+    f64: four exact power-of-two rescalings bring ``|x|`` into the f32
+    normal range, the f32 exponent is read there, and one exact f64
+    comparison undoes the case where the f32 rounding crossed a power of
+    two."""
+    x = jnp.asarray(x)
+    if x.dtype != jnp.float64:
+        return jnp.frexp(x)[1]
+    ax = jnp.abs(x)
+    shift = jnp.zeros(x.shape, jnp.int32)
+    for t in (512, 256, 128, 64):       # ends in [2^-114, 2^64)
+        big = ax >= 2.0 ** t
+        small = ax < 2.0 ** -t
+        ax = jnp.where(big, ax * 2.0 ** -t,
+                       jnp.where(small, ax * 2.0 ** t, ax))
+        shift = shift + jnp.where(big, t, jnp.where(small, -t, 0))
+    _, e32 = jnp.frexp(ax.astype(jnp.float32))
+    floor = _pow2_f32(e32 - 1).astype(jnp.float64)
+    e = e32 - (ax < floor).astype(jnp.int32) + shift
+    special = jnp.isinf(x) | jnp.isnan(x) | (x == 0)
+    return jnp.where(special, 0, e).astype(jnp.int32)
+
+
+@jax.jit
+def ldexp(x, n):
+    """``jnp.ldexp(x, n)`` bitwise wherever the result is a normal
+    number: ``x * 2^n`` with one rounding.
+
+    f64: two multiplies by exact powers of two, the first by
+    ``2^clip(n, -1022, 1023)``. For n in that range the second factor is
+    1; above it both scale up (exact until a true overflow); below it
+    the first step is exact for |x| >= 1. Only results under 2^-1022 can
+    round twice — the subnormal range XLA:CPU flushes anyway (subnormal
+    inputs, which CPU arithmetic flushes too, are outside the contract)."""
+    x = jnp.asarray(x)
+    if x.dtype != jnp.float64:
+        return jnp.ldexp(x, n)
+    n = jnp.asarray(n, jnp.int32)
+    first = jnp.clip(n, -1022, 1023)
+    out = x * _pow2_f64(first) * _pow2_f64(n - first)
+    return jnp.where(jnp.isinf(x) | (x == 0), x, out)   # no 0 * inf
+
+
+def native_f64() -> bool:
+    """Whether float64 routes run on the default backend: every backend
+    but XLA:TPU, which has no float64 unit and emulates it."""
+    return jax.default_backend() != "tpu"
+
+
+def refuse_f64_on_tpu(what: str) -> None:
+    """Refuse a float64 route on a TPU backend.
+
+    XLA:TPU rewrites float64 into 32-bit operations; the error-free split
+    and the f64 accumulation assume IEEE binary64, which nothing shows
+    that emulation to be (``chip_smoke.py``'s device phase probes it).
+    The route that runs on a TPU is the double-float32 one, and it is
+    never taken silently.
+    """
+    if not native_f64():
+        raise TypeError(
+            f"{what}: float64 operands are refused on TPU (XLA:TPU emulates "
+            f"float64; the f64 routes assume IEEE binary64). Use the DW "
+            f"(df32) route: repro.matmul(DW(a_hi, a_lo), DW(b_hi, b_lo), "
+            f"precision='ozaki-fp64/pallas_fused+epilogue'), with "
+            f"core.xmath.df32_from_f64 to split float64 data")
+
+
+# ----------------------------------------------------------------------------
 # df32 <-> f64 conversion (CPU-side bridging; f64 requires x64 mode)
 # ----------------------------------------------------------------------------
 

@@ -36,13 +36,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .launch import (crt_blocks, gemm_blocks, grid_for, pad_tail,
+from .launch import (crt_blocks, gemm_blocks, grid_for, kernel_jit, pad_tail,
                      streaming_blocks)
 from .ozaki_accum import dw_accum_step
 from .ozaki_split import split_tile
@@ -62,11 +63,10 @@ def _kernel(a_ref, b_ref, o_ref):
     o_ref[...] += prod
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("bm", "bn", "bk", "interpret"))
 def int8_matmul_nt(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
                    bn: int = 256, bk: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """C = A @ B_t.T with int32 accumulation. a: (m, k) int8, b_t: (n, k)."""
     assert a.dtype == jnp.int8 and b_t.dtype == jnp.int8
     m, k = a.shape
@@ -106,11 +106,10 @@ def _kernel_batched(a_ref, b_ref, o_ref):
     o_ref[...] += prod[None]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("bm", "bn", "bk", "interpret"))
 def int8_matmul_nt_batched(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
                            bn: int = 256, bk: int = 512,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """C[b] = A[b] @ B_t[b].T for every batch row, one kernel launch.
 
     a: (B, m, k) int8, b_t: (B, n, k) int8 -> (B, m, n) int32. The batch
@@ -337,13 +336,13 @@ def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
     return [o[:, :m, :n] for o in outs]
 
 
-@functools.partial(jax.jit, static_argnames=("p_lo", "t", "npairs", "scale",
-                                             "bm", "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("p_lo", "t", "npairs", "scale", "bm", "bn",
+                             "bk", "interpret"))
 def int8_matmul_nt_epilogue_sw(a_slices: jax.Array, b_slices: jax.Array,
                                c: jax.Array, *, p_lo: int, t: int,
                                npairs: int, scale: float, bm: int = 256,
                                bn: int = 256, bk: int = 512,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: Optional[bool] = None) -> jax.Array:
     """c += (sum_pp A[p_lo+pp] @ B[t-p_lo-pp].T) * scale, epilogue-fused.
 
     a_slices: (s, m, k) int8; b_slices: (s, n, k) int8; c: (m, n) float
@@ -358,13 +357,13 @@ def int8_matmul_nt_epilogue_sw(a_slices: jax.Array, b_slices: jax.Array,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("p_lo", "t", "npairs", "scale",
-                                             "bm", "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("p_lo", "t", "npairs", "scale", "bm", "bn",
+                             "bk", "interpret"))
 def int8_matmul_nt_epilogue_dw(a_slices: jax.Array, b_slices: jax.Array,
                                c_hi: jax.Array, c_lo: jax.Array, *,
                                p_lo: int, t: int, npairs: int, scale: float,
                                bm: int = 256, bn: int = 256, bk: int = 512,
-                               interpret: bool = True
+                               interpret: Optional[bool] = None
                                ) -> tuple[jax.Array, jax.Array]:
     """(c_hi, c_lo) += df32(group product) * scale, epilogue-fused.
 
@@ -480,12 +479,11 @@ def _crt_kernel_batched(moduli, qmod, inv, scales, nk, a_ref, b_ref, o_ref,
         o_ref[...] = _crt_epilogue(acc_ref, moduli, qmod, inv, scales)[None]
 
 
-@functools.partial(jax.jit, static_argnames=("moduli", "qmod", "inv",
-                                             "scales", "bm", "bn", "bk",
-                                             "interpret"))
+@kernel_jit(static_argnames=("moduli", "qmod", "inv", "scales", "bm", "bn",
+                             "bk", "interpret"))
 def int8_matmul_nt_crt(ra: jax.Array, rb: jax.Array, *, moduli, qmod, inv,
                        scales, bm: int = 256, bn: int = 256, bk: int = 512,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """Fused residue GEMMs + balanced-Garner CRT reconstruction.
 
     ra: (ell, m, k) int8 centered residue stack of A_int; rb: (ell, n, k)
@@ -738,9 +736,9 @@ def _streaming_launch(a_ops, b_ops, c_arrays, kernel, *, num_splits, p_lo,
                                      num_splits_b=ns_b,
                                      el_bytes=a_hi.dtype.itemsize)
     a_p = [pad_tail(a_hi, (bm_, bk_)), pad_tail(a_lo, (bm_, bk_)),
-           pad_tail(a_exp, (bm_,))]
+           pad_tail(a_exp[..., None], (bm_, 1))]
     b_p = [pad_tail(b_hi, (bn_, bk_)), pad_tail(b_lo, (bn_, bk_)),
-           pad_tail(b_exp, (bn_,))]
+           pad_tail(b_exp[..., None], (bn_, 1))]
     c_p = [pad_tail(c, (bm_, bn_)) for c in c_arrays]
     mp, kp = a_p[0].shape
     np_, _ = b_p[0].shape
@@ -753,10 +751,10 @@ def _streaming_launch(a_ops, b_ops, c_arrays, kernel, *, num_splits, p_lo,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk, pp: (i, kk)),
             pl.BlockSpec((bm_, bk_), lambda i, j, kk, pp: (i, kk)),
-            pl.BlockSpec((bm_,), lambda i, j, kk, pp: (i,)),
+            pl.BlockSpec((bm_, 1), lambda i, j, kk, pp: (i, jnp.int32(0))),
             pl.BlockSpec((bn_, bk_), lambda i, j, kk, pp: (j, kk)),
             pl.BlockSpec((bn_, bk_), lambda i, j, kk, pp: (j, kk)),
-            pl.BlockSpec((bn_,), lambda i, j, kk, pp: (j,)),
+            pl.BlockSpec((bn_, 1), lambda i, j, kk, pp: (j, jnp.int32(0))),
         ] + [c_spec] * nc,
         out_specs=[c_spec] * nc,
         out_shape=[jax.ShapeDtypeStruct((mp, np_), c.dtype) for c in c_p],
@@ -783,9 +781,9 @@ def _streaming_launch_batched(a_ops, b_ops, c_arrays, kernel, *, ns_a, ns_b,
                                      num_splits_b=ns_b,
                                      el_bytes=a_hi.dtype.itemsize)
     a_p = [pad_tail(a_hi, (bm_, bk_)), pad_tail(a_lo, (bm_, bk_)),
-           pad_tail(a_exp, (bm_,))]
+           pad_tail(a_exp[..., None], (bm_, 1))]
     b_p = [pad_tail(b_hi, (bn_, bk_)), pad_tail(b_lo, (bn_, bk_)),
-           pad_tail(b_exp, (bn_,))]
+           pad_tail(b_exp[..., None], (bn_, 1))]
     c_p = [pad_tail(c, (bm_, bn_)) for c in c_arrays]
     _, mp, kp = a_p[0].shape
     _, np_, _ = b_p[0].shape
@@ -798,10 +796,12 @@ def _streaming_launch_batched(a_ops, b_ops, c_arrays, kernel, *, ns_a, ns_b,
         in_specs=[
             pl.BlockSpec((1, bm_, bk_), lambda b, i, j, kk, pp: (b, i, kk)),
             pl.BlockSpec((1, bm_, bk_), lambda b, i, j, kk, pp: (b, i, kk)),
-            pl.BlockSpec((1, bm_), lambda b, i, j, kk, pp: (b, i)),
+            pl.BlockSpec((1, bm_, 1),
+                         lambda b, i, j, kk, pp: (b, i, jnp.int32(0))),
             pl.BlockSpec((1, bn_, bk_), lambda b, i, j, kk, pp: (b, j, kk)),
             pl.BlockSpec((1, bn_, bk_), lambda b, i, j, kk, pp: (b, j, kk)),
-            pl.BlockSpec((1, bn_), lambda b, i, j, kk, pp: (b, j)),
+            pl.BlockSpec((1, bn_, 1),
+                         lambda b, i, j, kk, pp: (b, j, jnp.int32(0))),
         ] + [c_spec] * nc,
         out_specs=[c_spec] * nc,
         out_shape=[jax.ShapeDtypeStruct((B, mp, np_), c.dtype) for c in c_p],
@@ -814,16 +814,15 @@ def _streaming_launch_batched(a_ops, b_ops, c_arrays, kernel, *, ns_a, ns_b,
     return [o[:, :m, :n] for o in outs]
 
 
-@functools.partial(jax.jit, static_argnames=("num_splits", "p_lo", "t",
-                                             "npairs", "w", "scale", "bm",
-                                             "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("num_splits", "p_lo", "t", "npairs", "w",
+                             "scale", "bm", "bn", "bk", "interpret"))
 def int8_matmul_nt_streaming_sw(a_hi: jax.Array, a_lo: jax.Array,
                                 a_exp: jax.Array, b_hi: jax.Array,
                                 b_lo: jax.Array, b_exp: jax.Array,
                                 c: jax.Array, *, num_splits: int, p_lo: int,
                                 t: int, npairs: int, w: int, scale: float,
                                 bm: int = 256, bn: int = 256, bk: int = 512,
-                                interpret: bool = True) -> jax.Array:
+                                interpret: Optional[bool] = None) -> jax.Array:
     """c += (sum_pp A[p_lo+pp] @ B[t-p_lo-pp].T) * scale — with the int8
     slices extracted in VMEM from the (hi, lo, exp) operand words.
 
@@ -840,9 +839,8 @@ def int8_matmul_nt_streaming_sw(a_hi: jax.Array, a_lo: jax.Array,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("num_splits", "p_lo", "t",
-                                             "npairs", "w", "scale", "bm",
-                                             "bn", "bk", "interpret"))
+@kernel_jit(static_argnames=("num_splits", "p_lo", "t", "npairs", "w",
+                             "scale", "bm", "bn", "bk", "interpret"))
 def int8_matmul_nt_streaming_dw(a_hi: jax.Array, a_lo: jax.Array,
                                 a_exp: jax.Array, b_hi: jax.Array,
                                 b_lo: jax.Array, b_exp: jax.Array,
@@ -850,7 +848,7 @@ def int8_matmul_nt_streaming_dw(a_hi: jax.Array, a_lo: jax.Array,
                                 num_splits: int, p_lo: int, t: int,
                                 npairs: int, w: int, scale: float,
                                 bm: int = 256, bn: int = 256, bk: int = 512,
-                                interpret: bool = True
+                                interpret: Optional[bool] = None
                                 ) -> tuple[jax.Array, jax.Array]:
     """(c_hi, c_lo) += df32(group product) * scale, streaming-split.
 

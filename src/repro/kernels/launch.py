@@ -17,6 +17,7 @@ launch parameters lower to Mosaic unchanged.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,33 @@ SUBLANE_F32 = 8     # second-to-last multiple, 4-byte dtypes
 SUBLANE_I8 = 32     # second-to-last multiple, 1-byte dtypes
 
 VMEM_BYTES = 16 * 2 ** 20   # per-core VMEM (v4/v5 class)
+SPLIT_LIVE_PLANES = 8       # float temporaries of the in-kernel split
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` means the host default, ``kernels.ops.INTERPRET``: Pallas
+    interpret mode off a TPU, Mosaic lowering on one."""
+    if interpret is not None:
+        return interpret
+    from . import ops
+    return ops.INTERPRET
+
+
+def kernel_jit(*, static_argnames: tuple[str, ...]):
+    """``jax.jit`` for a kernel wrapper with an ``interpret=None`` keyword.
+
+    The default is resolved before the call reaches the jit cache, so a
+    trace never bakes in a stale host default.
+    """
+    def wrap(fn):
+        jitted = jax.jit(fn, static_argnames=static_argnames)
+
+        @functools.wraps(fn)
+        def call(*args, interpret: Optional[bool] = None, **kwargs):
+            return jitted(*args, interpret=resolve_interpret(interpret),
+                          **kwargs)
+        return call
+    return wrap
 
 
 def align_up(x: int, align: int) -> int:
@@ -90,14 +118,19 @@ def _streaming_working_set(bm: int, bn: int, bk: int, *, num_splits_a: int,
     """VMEM bytes resident per streaming-GEMM grid step.
 
     Operand tiles arrive as (hi, lo) word pairs plus per-row exponent
-    vectors; the in-kernel split lands ``num_splits_a`` / ``num_splits_b``
-    int8 slice planes in persistent scratch next to the int32 product
-    accumulator and up to two carried float accumulator planes.
+    columns (an (r, 1) int32 block pads to 128 lanes); the in-kernel
+    split lands ``num_splits_a`` / ``num_splits_b`` int8 slice planes in
+    persistent scratch next to the int32 product accumulator and up to
+    two carried float accumulator planes, and keeps about
+    ``SPLIT_LIVE_PLANES`` float planes of the larger operand tile live
+    while it runs — without that term a 4096^3, s=9 launch picks
+    bk=512 and overflows the 16 MiB scoped VMEM.
     """
-    operands = 2 * el_bytes * (bm * bk + bn * bk) + 4 * (bm + bn)
+    operands = 2 * el_bytes * (bm * bk + bn * bk) + 4 * LANE * (bm + bn)
     slices = num_splits_a * bm * bk + num_splits_b * bn * bk
     accum = 4 * bm * bn + 2 * 2 * el_bytes * bm * bn   # int32 + in/out C
-    return operands + slices + accum
+    split = SPLIT_LIVE_PLANES * el_bytes * max(bm, bn) * bk
+    return operands + slices + accum + split
 
 
 def _crt_working_set(bm: int, bn: int, bk: int, *, ell: int) -> int:

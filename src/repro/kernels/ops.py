@@ -1,8 +1,11 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True (CPU validation). On a real TPU deployment
-set ``repro.kernels.ops.INTERPRET = False`` (or pass interpret=False) so
-``pl.pallas_call`` lowers to Mosaic.
+``INTERPRET`` is the one host default for Pallas interpret mode: False
+on a TPU backend (``pl.pallas_call`` lowers to Mosaic), True elsewhere
+(CPU validation). Every ``interpret=None`` default — the kernel wrappers,
+``OzakiConfig``/``ModularConfig``, ``PipelinePlan`` and the planners —
+resolves to it at launch or plan time; pass ``interpret=True`` to run
+the interpreter on a TPU on purpose.
 """
 from __future__ import annotations
 

@@ -24,6 +24,7 @@ In/out aliasing: the C operand(s) are donated and updated in place.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.xmath import two_sum
 
-from .launch import elementwise_blocks, grid_for, pad_tail
+from .launch import elementwise_blocks, grid_for, kernel_jit, pad_tail
 
 
 def dw_accum_step(p, c_hi, c_lo, scale: float):
@@ -72,10 +73,10 @@ def _accum_kernel(scale: float, p_ref, chi_ref, clo_ref, ohi_ref, olo_ref):
     olo_ref[...] = n_lo
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "interpret"))
+@kernel_jit(static_argnames=("scale", "bm", "bn", "interpret"))
 def accum_scaled_dw(p: jax.Array, c_hi: jax.Array, c_lo: jax.Array, *,
                     scale: float, bm: int = 256, bn: int = 256,
-                    interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                    interpret: Optional[bool] = None) -> tuple[jax.Array, jax.Array]:
     """(c_hi, c_lo) += df32(p) * scale, elementwise, fused in VMEM."""
     m, n = p.shape
     bm_, bn_ = elementwise_blocks(m, n, bm, bn)
@@ -103,10 +104,10 @@ def _accum_sw_kernel(scale: float, p_ref, c_ref, o_ref):
     o_ref[...] = c + p_ref[...].astype(c.dtype) * jnp.asarray(scale, c.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "interpret"))
+@kernel_jit(static_argnames=("scale", "bm", "bn", "interpret"))
 def accum_scaled_sw(p: jax.Array, c: jax.Array, *, scale: float,
                     bm: int = 256, bn: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """c += p * scale in c's (single-word) dtype, fused in VMEM.
 
     Used by the ``pallas_fused`` pipeline when ``accum="f64"``: the single

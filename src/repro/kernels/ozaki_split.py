@@ -20,6 +20,7 @@ Validated on CPU in interpret mode against ``repro.core.splitting``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,39 +28,48 @@ from jax.experimental import pallas as pl
 
 from repro.core.xmath import two_sum
 
-from .launch import grid_for, int8_tile_blocks, pad_tail
+from .launch import grid_for, int8_tile_blocks, kernel_jit, pad_tail
 
 
 def split_tile(out_ref, hi, lo, exp, num_splits: int, w: int):
     """Emit ``num_splits`` int8 slices of a (bm, bk) tile into ``out_ref``.
 
-    The extraction is elementwise per (row, col) given the (full-row)
-    exponent, so any tiling of the operand produces bitwise-identical
-    slices — the streaming GEMM kernels call this on VMEM scratch refs
-    with the same guarantee as the standalone split pass. The slice
-    chain is prefix-stable: the first p slices do not depend on how many
-    more will be extracted, so callers may size ``num_splits`` down to
-    just the prefix they consume.
+    ``exp`` is the (bm, 1) int32 column of full-row exponents. The
+    extraction is elementwise per (row, col) given that exponent, so any
+    tiling of the operand produces bitwise-identical slices — the
+    streaming GEMM kernels call this on VMEM scratch refs with the same
+    guarantee as the standalone split pass. The slice chain is
+    prefix-stable: the first p slices do not depend on how many more will
+    be extracted, so callers may size ``num_splits`` down to just the
+    prefix they consume.
+
+    Mosaic-legal arithmetic only: the sign is applied to the float slice
+    value (exact), and the int8 narrowing happens once, at the store —
+    the TPU VPU has no int8 multiply, and no int64 reaches the kernel.
     """
     neg = (hi < 0) | ((hi == 0) & (lo < 0))
-    sign = jnp.where(neg, -1, 1).astype(jnp.int8)
     a_hi = jnp.where(neg, -hi, hi)
     a_lo = jnp.where(neg, -lo, lo)
     # ldexp is exact (XLA's exp2 is not, even at integer arguments); the
     # scaled residual lands in [0, 1) like Algorithm 4 requires.
-    r_hi = jnp.ldexp(a_hi, -exp[:, None])
-    r_lo = jnp.ldexp(a_lo, -exp[:, None])
+    r_hi = jnp.ldexp(a_hi, -exp)
+    r_lo = jnp.ldexp(a_lo, -exp)
     scale = jnp.asarray(2.0 ** w, hi.dtype)
 
-    for p in range(num_splits):
+    # a loop, not a Python unroll: XLA:CPU takes minutes to compile the
+    # unrolled f32 chain at s >= 9 in interpret mode
+    def body(p, carry):
+        r_hi, r_lo = carry
         t = r_hi * scale
         u = r_lo * scale
         s, e = two_sum(t, u)
         y = jnp.clip(jnp.floor(s), -128, 127)
         f_hi, f_e = two_sum(s, -y)
         r_hi, t1 = two_sum(f_hi, e)
-        r_lo = t1 + f_e
-        out_ref[p, :, :] = sign * y.astype(jnp.int8)
+        out_ref[p] = jnp.where(neg, -y, y).astype(jnp.int32).astype(jnp.int8)
+        return r_hi, t1 + f_e
+
+    jax.lax.fori_loop(0, num_splits, body, (r_hi, r_lo))
 
 
 def _split_kernel(num_splits: int, w: int, hi_ref, lo_ref, exp_ref, out_ref):
@@ -67,18 +77,17 @@ def _split_kernel(num_splits: int, w: int, hi_ref, lo_ref, exp_ref, out_ref):
                num_splits, w)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_splits", "w", "bm", "bk", "interpret"))
+@kernel_jit(static_argnames=("num_splits", "w", "bm", "bk", "interpret"))
 def fused_split_dw(hi: jax.Array, lo: jax.Array, exp: jax.Array, *,
                    num_splits: int, w: int, bm: int = 256, bk: int = 256,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """All-slices-in-one-pass SplitInt. Returns (s, m, k) int8."""
     m, k = hi.shape
     # bm is the second-to-last dim of the int8 OUTPUT block: 32-sublane.
     bm_, bk_ = int8_tile_blocks(m, k, bm, bk)
     hi = pad_tail(hi, (bm_, bk_))
     lo = pad_tail(lo, (bm_, bk_))
-    exp = pad_tail(exp, (bm_,))
+    exp = pad_tail(exp[:, None], (bm_, 1))
     mp, kp = hi.shape
     out = pl.pallas_call(
         functools.partial(_split_kernel, num_splits, w),
@@ -86,9 +95,10 @@ def fused_split_dw(hi: jax.Array, lo: jax.Array, exp: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j: (i, j)),
             pl.BlockSpec((bm_, bk_), lambda i, j: (i, j)),
-            pl.BlockSpec((bm_,), lambda i, j: (i,)),
+            pl.BlockSpec((bm_, 1), lambda i, j: (i, jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((num_splits, bm_, bk_), lambda i, j: (0, i, j)),
+        out_specs=pl.BlockSpec((num_splits, bm_, bk_),
+                               lambda i, j: (jnp.int32(0), i, j)),
         out_shape=jax.ShapeDtypeStruct((num_splits, mp, kp), jnp.int8),
         interpret=interpret,
     )(hi, lo, exp)

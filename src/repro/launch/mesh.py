@@ -8,27 +8,18 @@ Single-pod mesh (16, 16) = ("data", "model"); multi-pod adds a leading
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5 has explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                     # jax 0.4.x: meshes are Auto already
-    AxisType = None
-
-
-def _axis_kwargs(n: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
+    """``jax.make_mesh`` with Auto axis types (GSPMD-propagated sharding,
+    which the k-shard routes and the model's sharding plans rely on).
 
-    The single place the repo constructs meshes, so the jax-version
-    dance happens once (``axis_types`` only exists in newer jax).
+    The single place the repo constructs meshes.
     """
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(tuple(axes))))
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import init_model
 from repro.serving.engine import (Request, ServingEngine,
                                   generate_sequential)
@@ -58,6 +59,7 @@ def main():
                          "meeting --target-error (or drop the last "
                          "anti-diagonal without one)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     if cfg.frontend == "audio":

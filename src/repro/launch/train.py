@@ -27,6 +27,7 @@ import numpy as np
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs import get_config
 from repro.data.pipeline import make_data
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.parallel.sharding import make_plan
 from repro.runtime.fault import (Heartbeat, SimulatedFailure, StepWatchdog,
@@ -140,7 +141,9 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--max-restarts", type=int, default=2)
     ap.add_argument("--simulate-failure-at", type=int, default=-1)
-    train(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    train(args)
 
 
 if __name__ == "__main__":

@@ -13,7 +13,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def psum_exact_int32(x: jax.Array, axis: str) -> jax.Array:

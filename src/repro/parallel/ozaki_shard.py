@@ -90,7 +90,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.executors import gemm_xla, get_executor, int32_to_dw
 from repro.core.modular import (ModularConfig, center_mod, crt_digits,
@@ -98,7 +97,7 @@ from repro.core.modular import (ModularConfig, center_mod, crt_digits,
                                 residues_from_slices, usable_moduli)
 from repro.core.ozaki import OzakiConfig, resolve_accuracy_config
 from repro.core.splitting import SplitResult, row_exponents, split_int
-from repro.core.xmath import DW, dw_add
+from repro.core.xmath import DW, dw_add, ldexp, refuse_f64_on_tpu
 from repro.parallel.collectives import (psum_exact_int32, reduce_scatter_sum,
                                         ring_all_gather)
 from repro.parallel.compression import (ResidueWire, SliceWire, pack_residues,
@@ -139,12 +138,12 @@ def _accumulate(prods, ea, eb, cfg: OzakiConfig, w: int):
             scale = jnp.float32(2.0 ** (-(t + 2) * w))
             term = int32_to_dw(p_t)
             acc = dw_add(acc, DW(term.hi * scale, term.lo * scale))
-        hi = jnp.ldexp(acc.hi, e_base)
-        lo = jnp.ldexp(acc.lo, e_base)
+        hi = ldexp(acc.hi, e_base)
+        lo = ldexp(acc.lo, e_base)
         return hi, lo                     # df32 pair (48 mantissa bits)
     c = jnp.zeros(shape, jnp.float64)
     for t, p_t in sorted(prods, key=lambda tp: -tp[0]):
-        c = c + jnp.ldexp(p_t.astype(jnp.float64), e_base - (t + 2) * w)
+        c = c + ldexp(p_t.astype(jnp.float64), e_base - (t + 2) * w)
     return c
 
 
@@ -226,6 +225,8 @@ def distributed_ozaki_matmul(a: jax.Array, b: jax.Array, mesh: Mesh,
     if schedule not in KSHARD_SCHEDULES:
         raise ValueError(f"unknown schedule {schedule!r}; expected one of "
                          f"{KSHARD_SCHEDULES}")
+    if a.dtype == jnp.float64 or cfg.accum == "f64":
+        refuse_f64_on_tpu("distributed_ozaki_matmul")
     k_global = a.shape[1]
     # fast-mode/target resolution BEFORE sizing the width, exactly like
     # the single-device drivers — required for bitwise parity on the
@@ -243,9 +244,9 @@ def distributed_ozaki_matmul(a: jax.Array, b: jax.Array, mesh: Mesh,
     col = axis if schedule in ("reduce_scatter", "rs_stream") else None
     c_spec = P(row, col)
     out_specs = (c_spec, c_spec) if cfg.accum == "df32" else c_spec
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(row, axis), P(axis, None)),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(row, axis), P(axis, None)),
+                               out_specs=out_specs, check_vma=False))
     out = fn(a, b)
     return DW(*out) if cfg.accum == "df32" else out
 
@@ -271,6 +272,8 @@ def distributed_ozaki_matmul_batched(a: jax.Array, b: jax.Array, mesh: Mesh,
     if schedule not in KSHARD_SCHEDULES:
         raise ValueError(f"unknown schedule {schedule!r}; expected one of "
                          f"{KSHARD_SCHEDULES}")
+    if a.dtype == jnp.float64 or cfg.accum == "f64":
+        refuse_f64_on_tpu("distributed_ozaki_matmul_batched")
     _, m, k_global = a.shape
     cfg = resolve_accuracy_config(cfg, k_global)
     w = cfg.width_for(k_global)
@@ -288,9 +291,9 @@ def distributed_ozaki_matmul_batched(a: jax.Array, b: jax.Array, mesh: Mesh,
     col = axis if schedule in ("reduce_scatter", "rs_stream") else None
     c_spec = P(row, None, col)
     out_specs = (c_spec, c_spec) if cfg.accum == "df32" else c_spec
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(row, None, axis), P(axis, None)),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(row, None, axis), P(axis, None)),
+                               out_specs=out_specs, check_vma=False))
     out = fn(a, b)
     return DW(*out) if cfg.accum == "df32" else out
 
@@ -325,6 +328,7 @@ def ozaki_matmul_mnshard(a: jax.Array, b: jax.Array, mesh: Mesh,
     if schedule not in MNSHARD_SCHEDULES:
         raise ValueError(f"unknown schedule {schedule!r}; expected one of "
                          f"{MNSHARD_SCHEDULES}")
+    refuse_f64_on_tpu("ozaki_matmul_mnshard")
     if cfg.accum != "f64":
         raise ValueError("ozaki_matmul_mnshard is the f64 layout; use the "
                          "k-shard schedules for df32")
@@ -371,10 +375,10 @@ def ozaki_matmul_mnshard(a: jax.Array, b: jax.Array, mesh: Mesh,
                   exp[None, :].astype(jnp.int32))
         return ex.contract(sa, sb, w, e_base, (a_blk.shape[0], n))
 
-    # check_rep=False: Pallas kernels have no shard_map replication rule
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis, None), P(None, axis)),
-                   out_specs=P(axis, None), check_rep=False)
+    # check_vma=False: Pallas kernels have no shard_map replication rule
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(axis, None), P(None, axis)),
+                               out_specs=P(axis, None), check_vma=False))
     return fn(a, b)
 
 
@@ -442,6 +446,7 @@ def distributed_ozaki2_matmul(a: jax.Array, b: jax.Array, mesh: Mesh,
     if a.dtype != jnp.float64 or b.dtype != jnp.float64:
         raise TypeError(f"distributed_ozaki2_matmul takes f64 operands, "
                         f"got {a.dtype} @ {b.dtype}")
+    refuse_f64_on_tpu("distributed_ozaki2_matmul")
     k_global = a.shape[1]
     plan = cfg.plan(k_global)
     moduli = usable_moduli(k_global)[:plan.num_moduli]
@@ -451,9 +456,9 @@ def distributed_ozaki2_matmul(a: jax.Array, b: jax.Array, mesh: Mesh,
         return _kshard_local2(a_blk, b_blk, plan, moduli, w, axis, schedule)
 
     col = axis if schedule == "reduce_scatter" else None
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, axis), P(axis, None)),
-                   out_specs=P(None, col), check_rep=False)
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(None, axis), P(axis, None)),
+                               out_specs=P(None, col), check_vma=False))
     return fn(a, b)
 
 
@@ -472,6 +477,7 @@ def ozaki2_matmul_mnshard(a: jax.Array, b: jax.Array, mesh: Mesh,
     executor computes, so every backend — including the fused-CRT
     epilogue kernel — contracts it to the bitwise-identical result.
     """
+    refuse_f64_on_tpu("ozaki2_matmul_mnshard")
     world = mesh.shape[axis]
     k = a.shape[1]
     plan = cfg.plan(k)
@@ -500,15 +506,15 @@ def ozaki2_matmul_mnshard(a: jax.Array, b: jax.Array, mesh: Mesh,
                                      inv=inv, scales=scales, bm=tile.bm,
                                      bn=tile.bn, bk=tile.bk,
                                      interpret=plan.interpret)
-            return jnp.ldexp(out, e_base)
+            return ldexp(out, e_base)
         p = ex.gemm(ra, rb)
         digits = crt_digits(center_mod(p, moduli), moduli)
         return crt_value(digits, moduli, plan.beta, e_base)
 
-    # check_rep=False: Pallas kernels have no shard_map replication rule
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis, None), P(None, axis)),
-                   out_specs=P(axis, None), check_rep=False)
+    # check_vma=False: Pallas kernels have no shard_map replication rule
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P(axis, None), P(None, axis)),
+                               out_specs=P(axis, None), check_vma=False))
     return fn(a, b)
 
 

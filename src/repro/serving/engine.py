@@ -263,7 +263,6 @@ class ServingEngine:
         from repro.api import policy_of
         from repro.core.autotune import plan_cache_key
         from repro.core.tuning import select_pipeline_plan
-        from repro.kernels.ops import INTERPRET
         cfg = self.cfg
         pol = policy_of(cfg)             # one object carries every knob
         for k, n in ozaki_projection_shapes(cfg):
@@ -276,7 +275,7 @@ class ServingEngine:
                 1, n, k, batch=self.num_slots, broadcast_weights=True,
                 backend=pol.backend, accum="df32",
                 num_splits=pol.num_splits,
-                fuse_epilogue=pol.fuse_epilogue, interpret=INTERPRET,
+                fuse_epilogue=pol.fuse_epilogue,
                 target_error=pol.target_error, fast_mode=pol.fast_mode,
                 dtype="float32", cache=self.plan_cache,
                 autotune=self.autotune_plans)

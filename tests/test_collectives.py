@@ -17,7 +17,6 @@ def test_ring_all_gather_matches_lax_all_gather():
     out = run_multidevice("""
 import jax, numpy as np, jax.numpy as jnp
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh_compat
 from repro.parallel.collectives import ring_all_gather
@@ -29,15 +28,15 @@ x = jnp.asarray(rng.standard_normal((16, 5)), jnp.float32)
 def run(hop):
     def local(blk):
         return ring_all_gather(blk, 'data', 8, hop=hop)
-    return shard_map(local, mesh=mesh, in_specs=P('data'),
-                     out_specs=P(), check_rep=False)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=P('data'),
+                         out_specs=P(), check_vma=False)(x)
 
 def ref():
     def local(blk):
         g = jax.lax.all_gather(blk, 'data')      # (8, chunk, 5)
         return g.reshape(-1, g.shape[-1])
-    return shard_map(local, mesh=mesh, in_specs=P('data'),
-                     out_specs=P(), check_rep=False)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=P('data'),
+                         out_specs=P(), check_vma=False)(x)
 
 r = np.asarray(ref())
 assert np.array_equal(r, np.asarray(x))          # sanity: gather restores x
@@ -70,7 +69,6 @@ def test_reduce_scatter_sum_int32_exact():
     on (associative integer adds, any reduction order)."""
     out = run_multidevice("""
 import jax, numpy as np, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh_compat
 from repro.parallel.collectives import psum_exact_int32, reduce_scatter_sum
@@ -86,10 +84,10 @@ def local_rs(v):
 def local_psum(v):
     return psum_exact_int32(v[0], 'data')
 
-rs = shard_map(local_rs, mesh=mesh, in_specs=P('data', None, None),
-               out_specs=P(None, 'data'), check_rep=False)(vals)
-tot = shard_map(local_psum, mesh=mesh, in_specs=P('data', None, None),
-                out_specs=P(), check_rep=False)(vals)
+rs = jax.shard_map(local_rs, mesh=mesh, in_specs=P('data', None, None),
+                   out_specs=P(None, 'data'), check_vma=False)(vals)
+tot = jax.shard_map(local_psum, mesh=mesh, in_specs=P('data', None, None),
+                    out_specs=P(), check_vma=False)(vals)
 exact = np.asarray(vals, np.int64).sum(axis=0)
 assert np.array_equal(np.asarray(tot, np.int64), exact)
 assert np.array_equal(np.asarray(rs, np.int64), exact)

@@ -196,7 +196,6 @@ def test_ef_residual_stays_bounded():
     the current round — the EF-SGD boundedness that makes the compressed
     sum converge (mesh-level convergence is covered in
     test_distributed)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh_compat
     from repro.parallel.compression import (EFState, compress_psum,
@@ -204,12 +203,13 @@ def test_ef_residual_stays_bounded():
     mesh = make_mesh_compat((1,), ("data",))      # axis of size 1: exact psum
     rng = np.random.default_rng(4)
 
+    @jax.jit                  # one compile, not one per eager primitive
     def one_round(g, r):
         def local(g, r):
             avg, ef = compress_psum({"g": g}, EFState({"g": r}), "data")
             return avg["g"], ef.residual["g"]
-        return shard_map(local, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=(P(), P()), check_rep=False)(g, r)
+        return jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=(P(), P()), check_vma=False)(g, r)
 
     g0 = jnp.asarray(rng.standard_normal(256), jnp.float32)
     res = jnp.asarray(init_ef_state({"g": g0}).residual["g"])

@@ -458,3 +458,19 @@ def test_plan_for_threads_comm():
     assert back.comm == "int8" and back.shard_axis == "model"
     sel = select_pipeline_plan(64, 64, 512, shard_axis="model", comm="int8")
     assert sel.comm == "int8"
+
+
+@pytest.mark.parametrize("host_default", [True, False])
+def test_interpret_default_follows_host(monkeypatch, host_default):
+    """Every ``interpret=None`` default resolves to the one host default,
+    ``kernels.ops.INTERPRET`` (on off a TPU, off on one), when the plan
+    is built; an explicit value always wins."""
+    from repro.core.modular import ModularConfig
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "INTERPRET", host_default)
+    assert OzakiConfig().plan().interpret is host_default
+    assert ModularConfig().plan(64).interpret is host_default
+    assert select_pipeline_plan(64, 64, 64).interpret is host_default
+    assert PipelinePlan().interpret is host_default
+    assert OzakiConfig(interpret=True).plan().interpret is True
+    assert PipelinePlan(interpret=False).interpret is False

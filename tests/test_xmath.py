@@ -1,6 +1,7 @@
 """Error-free transform exactness — verified against exact rationals."""
 from fractions import Fraction
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -138,3 +139,81 @@ def test_rel_error_vs_dd_zero_safe():
     c = np.array([[1.0, 0.0]])
     err = rel_error_vs_dd(c, np.array([[1.0, 0.0]]), np.zeros((1, 2)))
     assert np.all(err == 0)
+
+
+def _xmath_case(name):
+    """(x, n) pairs the pipeline feeds ``xmath.exponent``/``ldexp``."""
+    from repro.core.splitting import ZERO_ROW_EXP
+    rng = np.random.default_rng(11)
+    k = np.arange(-1022, 1024)
+    if name == "zeros":
+        return np.array([0.0, -0.0, 0.0, -0.0]), np.array([0, 7, -60, 2047])
+    if name == "powers_of_two":
+        x = np.concatenate([np.ldexp(1.0, k), -np.ldexp(1.0, k)])
+        return x, rng.integers(-60, 61, x.shape)
+    if name == "zero_row_exp":
+        # an all-zero row is split against the finite sentinel exponent
+        return np.zeros(64), np.full(64, -ZERO_ROW_EXP)
+    if name == "phi2":
+        # paper Eq. 6 at phi = 2: the widest magnitude spread it uses
+        x = (rng.uniform(-0.5, 0.5, 4096)
+             * np.exp(2.0 * rng.standard_normal(4096)))
+        return x, -rng.integers(-40, 41, x.shape)
+    if name == "extreme_row_exponents":
+        # the largest and smallest normal rows, and the int32 slice
+        # products scaled by 2^(ea + eb - (t + 2) w) at both ends
+        big = np.nextafter(np.ldexp(1.0, k[-8:]), np.inf)
+        x = np.concatenate([np.ldexp(1.0, [-1022, -1021, 1023]),
+                            [np.finfo(np.float64).max], big, -big,
+                            rng.integers(-2 ** 31, 2 ** 31, 64) * 1.0])
+        n = np.concatenate([[1021, 1021, -1024, -1024],
+                            np.full(16, -1024),
+                            rng.integers(-2146, 2049, 64)])
+        return x, n
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", ["zeros", "powers_of_two", "zero_row_exp",
+                                  "phi2", "extreme_row_exponents"])
+def test_exponent_and_ldexp_bitwise_match_jnp(name):
+    """``xmath.exponent``/``xmath.ldexp`` build f64 exponents and scales
+    without a 64-bit bitcast (XLA:TPU has none); on the inputs the
+    pipeline feeds them they are bitwise ``jnp.frexp``/``jnp.ldexp``."""
+    from repro.core.xmath import exponent, ldexp
+    x, n = _xmath_case(name)
+    x = jnp.asarray(x, jnp.float64)
+    n = jnp.asarray(n, jnp.int32)
+    np.testing.assert_array_equal(np.asarray(jax.jit(exponent)(x)),
+                                  np.asarray(jnp.frexp(x)[1]))
+    got = np.asarray(jax.jit(ldexp)(x, n))
+    want = np.asarray(jnp.ldexp(x, n))
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+    # f32 goes straight to jnp: the df32 path is unchanged
+    x32 = x.astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(exponent(x32)),
+                                  np.asarray(jnp.frexp(x32)[1]))
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_f64_routes_refused_only_on_tpu(monkeypatch, backend):
+    """XLA:TPU float64 is not IEEE binary64, so f64 operands are refused
+    there with an error naming the DW route; elsewhere they run. The DW
+    route runs on both."""
+    import repro
+    from repro.core.xmath import DW
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    a = jnp.asarray(np.random.default_rng(5).standard_normal((8, 16)))
+    b = a.T
+    for spec in ("ozaki-fp64x3", "ozaki2-fp64"):
+        if backend == "tpu":
+            with pytest.raises(TypeError, match=r"refused on TPU.*DW"):
+                repro.matmul(a, b, precision=spec)
+        else:
+            assert np.isfinite(np.asarray(
+                repro.matmul(a, b, precision=spec))).all()
+    dw = repro.matmul(df32_from_f64(a), df32_from_f64(b),
+                      precision="ozaki-fp64x9")
+    assert isinstance(dw, DW)
+    ref = np.asarray(a) @ np.asarray(b)
+    np.testing.assert_allclose(np.asarray(df32_to_f64(dw)), ref, rtol=0,
+                               atol=1e-12 * np.abs(ref).max())

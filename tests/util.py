@@ -14,7 +14,7 @@ REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_multidevice(script: str, n_devices: int = 8,
-                    timeout: int = 600) -> str:
+                    timeout: int = 300) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")

@@ -132,16 +132,22 @@ def run_dw(phase: str, a, b, rows, cols) -> None:
 
     a_hi, a_lo, a_val = to_dw(a)
     b_hi, b_lo, b_val = to_dw(b)
-    da = DW(jax.device_put(a_hi), jax.device_put(a_lo))
-    db = DW(jax.device_put(b_hi), jax.device_put(b_lo))
+    words = [jax.device_put(w) for w in (a_hi, a_lo, b_hi, b_lo)]
     del a_hi, a_lo, b_hi, b_lo
+    # one program, as compiled in tests/test_tpu_compile.py; run eagerly,
+    # stage by stage, a second 16384^3 call overran the 16 GiB of one v5e
+    gemm = jax.jit(lambda ah, al, bh, bl: repro.matmul(
+        DW(ah, al), DW(bh, bl), precision=SPEC))
     t0 = time.perf_counter()
-    out = jax.block_until_ready(repro.matmul(da, db, precision=SPEC))
+    out = jax.block_until_ready(gemm(*words))
     first = time.perf_counter() - t0
+    del out                    # two results do not fit beside the operands
     t0 = time.perf_counter()
-    out = jax.block_until_ready(repro.matmul(da, db, precision=SPEC))
+    out = jax.block_until_ready(gemm(*words))
     warm = time.perf_counter() - t0
-    del da, db
+    del words
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
     k = a.shape[-1]
     a2 = a_val.reshape(-1, k)
     hi = np.asarray(out.hi).reshape(-1, out.hi.shape[-1])
@@ -152,6 +158,7 @@ def run_dw(phase: str, a, b, rows, cols) -> None:
     bound = dw_bound(k)
     log(phase, shape=f"{a.shape}@{b.shape}", spec=SPEC,
         first_call_s=f"{first:.3f}", warm_call_s=f"{warm:.3f}",
+        process_peak_hbm_bytes=peak,
         block=f"{len(rows)}x{len(cols)}", scaled_err=f"{err:.3e}",
         df32_bound=f"{bound:.3e}")
     check(phase, np.isfinite(hi).all() and np.isfinite(lo).all(),
@@ -293,9 +300,11 @@ def phase_kshard(seed: int) -> None:
     t0 = time.perf_counter()
     a1, b1 = jax.device_put(a, one), jax.device_put(b, one)
     if f64:
-        ref = words(ozaki_matmul(a1, b1, cfg))
+        one_chip = jax.jit(lambda x, y: ozaki_matmul(x, y, cfg))
     else:
-        ref = words(ozaki_matmul_dw(DW(a1, 0 * a1), DW(b1.T, 0 * b1.T), cfg))
+        one_chip = jax.jit(lambda x, y: ozaki_matmul_dw(
+            DW(x, 0 * x), DW(y.T, 0 * y.T), cfg))
+    ref = words(one_chip(a1, b1))
     del a1, b1
     log("kshard", route="one-chip", accum=cfg.accum,
         first_call_s=f"{time.perf_counter() - t0:.3f}")

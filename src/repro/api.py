@@ -60,6 +60,8 @@ import re
 import threading
 from typing import Optional
 
+from repro import tracing
+
 SCHEMES = ("bf16", "int8_quant", "ozaki_fp64", "ozaki2_fp64")
 
 _SCHEME_RE = re.compile(r"^(?P<scheme>[a-z0-9_\-]+?)(?:x(?P<splits>\d+))?$")
@@ -511,14 +513,16 @@ def matmul(a, b, precision=None):
     ``b`` is always taken in natural ``(..., k, n)`` orientation — the
     front door transposes for the entries that want ``B^T`` (exact).
     """
-    pol = MatmulPolicy.of(precision)
-    if pol.scheme == "bf16":
-        return _matmul_bf16(a, b)
-    if pol.scheme == "int8_quant":
-        return _matmul_int8_quant(a, b)
-    if pol.scheme == "ozaki2_fp64":
-        return _matmul_ozaki2(a, b, pol)
-    return _matmul_ozaki_dispatch(a, b, pol)
+    tracing.count("matmul_traces")
+    with tracing.span(tracing.MATMUL), tracing.scope(tracing.MATMUL):
+        pol = MatmulPolicy.of(precision)
+        if pol.scheme == "bf16":
+            return _matmul_bf16(a, b)
+        if pol.scheme == "int8_quant":
+            return _matmul_int8_quant(a, b)
+        if pol.scheme == "ozaki2_fp64":
+            return _matmul_ozaki2(a, b, pol)
+        return _matmul_ozaki_dispatch(a, b, pol)
 
 
 def _matmul_bf16(a, b):
@@ -645,16 +649,19 @@ def _matmul_ozaki_dispatch(a, b, pol: MatmulPolicy):
             # broadcast weights: fold the batch into rows — exact, the
             # same fold the f32/f64 batched pipeline makes
             bsz, m, k = a.hi.shape
-            out = _matmul_ozaki_dispatch(
-                DW(a.hi.reshape(bsz * m, k), a.lo.reshape(bsz * m, k)), b,
-                pol)
-            return DW(out.hi.reshape(bsz, m, -1), out.lo.reshape(bsz, m, -1))
+            with tracing.scope(tracing.LAYOUT):
+                a = DW(a.hi.reshape(bsz * m, k), a.lo.reshape(bsz * m, k))
+            out = _matmul_ozaki_dispatch(a, b, pol)
+            with tracing.scope(tracing.LAYOUT):
+                return DW(out.hi.reshape(bsz, m, -1),
+                          out.lo.reshape(bsz, m, -1))
         if a.hi.ndim != 2 or b.hi.ndim != 2:
             raise ValueError(f"DW operands must be 2-D, or 3-D with 2-D "
                              f"weights, got {a.hi.shape} @ {b.hi.shape}")
         k = a.hi.shape[-1]
         cfg = pol.ozaki_config(k, accum="df32")
-        b_t = DW(b.hi.T, b.lo.T)               # exact: a permutation
+        with tracing.scope(tracing.LAYOUT):
+            b_t = DW(b.hi.T, b.lo.T)           # exact: a permutation
         cfg = _apply_tuned_plan(cfg, _active_plan_cache(pol),
                                 m=a.hi.shape[0], n=b.hi.shape[1], k=k,
                                 batch=1)
@@ -723,9 +730,11 @@ def _matmul_ozaki_dispatch(a, b, pol: MatmulPolicy):
     from repro.core.xmath import dw_to_single
     cfg = _apply_tuned_plan(pol.ozaki_config(k, accum="df32"), cache,
                             m=m, n=n, k=k, batch=1)
-    out = ozaki_matmul_dw(DW(a, jnp.zeros_like(a)),
-                          DW(b.T, jnp.zeros_like(b.T)), cfg)
-    return dw_to_single(out)
+    with tracing.scope(tracing.LAYOUT):
+        a, b_t = DW(a, jnp.zeros_like(a)), DW(b.T, jnp.zeros_like(b.T))
+    out = ozaki_matmul_dw(a, b_t, cfg)
+    with tracing.scope(tracing.SCALE_OUT):
+        return dw_to_single(out)
 
 
 # ----------------------------------------------------------------------------

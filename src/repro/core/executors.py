@@ -39,6 +39,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
+
 # core.modular imports core.tuning only; its drivers import us lazily,
 # so this top-level import is cycle-free.
 from .modular import (center_mod, crt_digits, crt_value, garner_constants,
@@ -85,9 +87,11 @@ class XlaExecutor:
         self.plan = plan
 
     # ---- stage 1: split -------------------------------------------------
+    @tracing.scoped(tracing.SPLIT)
     def split(self, x: jax.Array, w: int) -> SplitResult:
         return split_int(x, self.plan.num_splits, w)
 
+    @tracing.scoped(tracing.SPLIT)
     def split_dw(self, x: DW, w: int) -> SplitResult:
         return split_int_dw(x, self.plan.num_splits, w)
 
@@ -95,12 +99,17 @@ class XlaExecutor:
     def gemm(self, a8: jax.Array, bt8: jax.Array) -> jax.Array:
         return gemm_xla(a8, bt8)
 
+    @tracing.scoped(tracing.GEMM)
     def products(self, sa: SplitResult,
                  sb: SplitResult) -> list[tuple[int, jax.Array]]:
         """[(t, P_t int32)] per anti-diagonal group."""
         plan = self.plan
         out = []
         for t, pairs in plan.diagonals():
+            # one GEMM per group over the k-concatenated pairs, else one
+            # per pair
+            tracing.gemm_launch(len(pairs),
+                                launches=1 if plan.concat_k else len(pairs))
             if plan.concat_k:
                 a_cat = jnp.concatenate([sa.slices[p] for p, _ in pairs],
                                         axis=-1)
@@ -119,6 +128,7 @@ class XlaExecutor:
         return out
 
     # ---- stage 3: high-precision scaled accumulation -------------------
+    @tracing.scoped(tracing.GEMM)
     def accumulate(self, products, e_base: jax.Array, w: int, shape):
         if self.plan.accum == "f64":
             c = jnp.zeros(shape, jnp.float64)
@@ -131,7 +141,8 @@ class XlaExecutor:
             scale = jnp.float32(2.0 ** (-(t + 2) * w))  # exact power of two
             term = int32_to_dw(p_t)
             acc = dw_add(acc, DW(term.hi * scale, term.lo * scale))
-        return DW(ldexp(acc.hi, e_base), ldexp(acc.lo, e_base))
+        with tracing.scope(tracing.SCALE_OUT):
+            return DW(ldexp(acc.hi, e_base), ldexp(acc.lo, e_base))
 
     # ---- stages 2+3 -----------------------------------------------------
     def contract(self, sa: SplitResult, sb: SplitResult, w: int,
@@ -163,6 +174,7 @@ class FusedExecutor(PallasExecutor):
     kernels are elementwise, so the fold is exact.
     """
 
+    @tracing.scoped(tracing.SPLIT)
     def split(self, x: jax.Array, w: int) -> SplitResult:
         from repro.kernels import fused_split_dw
         exp = row_exponents(x)
@@ -173,6 +185,7 @@ class FusedExecutor(PallasExecutor):
                                 interpret=self.plan.interpret)
         return SplitResult(slices, exp, w)
 
+    @tracing.scoped(tracing.SPLIT)
     def split_dw(self, x: DW, w: int) -> SplitResult:
         from repro.kernels import fused_split_dw
         exp = row_exponents(x.hi)
@@ -183,6 +196,7 @@ class FusedExecutor(PallasExecutor):
                                 interpret=self.plan.interpret)
         return SplitResult(slices, exp, w)
 
+    @tracing.scoped(tracing.GEMM)
     def accumulate(self, products, e_base: jax.Array, w: int, shape):
         from repro.kernels import accum_scaled_dw, accum_scaled_sw
         tile = self.plan.tile
@@ -199,14 +213,16 @@ class FusedExecutor(PallasExecutor):
             for t, p_t in _ordered(products):
                 c = accum_scaled_sw(fold2d(p_t), c,
                                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return ldexp(c.reshape(shape), e_base)
+            with tracing.scope(tracing.SCALE_OUT):
+                return ldexp(c.reshape(shape), e_base)
         c_hi = fold2d(jnp.zeros(shape, jnp.float32))
         c_lo = fold2d(jnp.zeros(shape, jnp.float32))
         for t, p_t in _ordered(products):
             c_hi, c_lo = accum_scaled_dw(fold2d(p_t), c_hi, c_lo,
                                          scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(ldexp(c_hi.reshape(shape), e_base),
-                  ldexp(c_lo.reshape(shape), e_base))
+        with tracing.scope(tracing.SCALE_OUT):
+            return DW(ldexp(c_hi.reshape(shape), e_base),
+                      ldexp(c_lo.reshape(shape), e_base))
 
 
 class EpilogueExecutor(FusedExecutor):
@@ -239,6 +255,7 @@ class EpilogueExecutor(FusedExecutor):
                 groups.extend((t, p, 1) for p, _ in pairs)
         return sorted(groups, key=lambda g: -g[0])
 
+    @tracing.scoped(tracing.GEMM)
     def contract(self, sa: SplitResult, sb: SplitResult, w: int,
                  e_base: jax.Array, shape):
         from repro.kernels import (int8_matmul_nt_epilogue_dw,
@@ -250,17 +267,21 @@ class EpilogueExecutor(FusedExecutor):
         if self.plan.accum == "f64":
             c = jnp.zeros(shape, jnp.float64)
             for t, p_lo, npairs in self._groups():
+                tracing.gemm_launch(npairs)
                 c = int8_matmul_nt_epilogue_sw(
                     sa.slices, sb.slices, c, p_lo=p_lo, t=t, npairs=npairs,
                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return ldexp(c, e_base)
+            with tracing.scope(tracing.SCALE_OUT):
+                return ldexp(c, e_base)
         c_hi = jnp.zeros(shape, jnp.float32)
         c_lo = jnp.zeros(shape, jnp.float32)
         for t, p_lo, npairs in self._groups():
+            tracing.gemm_launch(npairs)
             c_hi, c_lo = int8_matmul_nt_epilogue_dw(
                 sa.slices, sb.slices, c_hi, c_lo, p_lo=p_lo, t=t,
                 npairs=npairs, scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
+        with tracing.scope(tracing.SCALE_OUT):
+            return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
 
 
 class StreamingSplit(NamedTuple):
@@ -292,12 +313,15 @@ class StreamingExecutor(EpilogueExecutor):
     to the materialized stacks — the parity matrix enforces it.
     """
 
+    @tracing.scoped(tracing.SPLIT)
     def split(self, x: jax.Array, w: int) -> StreamingSplit:
         return StreamingSplit(x, jnp.zeros_like(x), row_exponents(x), w)
 
+    @tracing.scoped(tracing.SPLIT)
     def split_dw(self, x: DW, w: int) -> StreamingSplit:
         return StreamingSplit(x.hi, x.lo, row_exponents(x.hi), w)
 
+    @tracing.scoped(tracing.GEMM)
     def contract(self, sa: StreamingSplit, sb: StreamingSplit, w: int,
                  e_base: jax.Array, shape):
         from repro.kernels import (int8_matmul_nt_streaming_dw,
@@ -312,17 +336,21 @@ class StreamingExecutor(EpilogueExecutor):
         if plan.accum == "f64":
             c = jnp.zeros(shape, jnp.float64)
             for t, p_lo, npairs in self._groups():
+                tracing.gemm_launch(npairs)
                 c = int8_matmul_nt_streaming_sw(
                     *a_ops, *b_ops, c, p_lo=p_lo, t=t, npairs=npairs,
                     scale=2.0 ** (-(t + 2) * w), **kw)
-            return ldexp(c, e_base)
+            with tracing.scope(tracing.SCALE_OUT):
+                return ldexp(c, e_base)
         c_hi = jnp.zeros(shape, jnp.float32)
         c_lo = jnp.zeros(shape, jnp.float32)
         for t, p_lo, npairs in self._groups():
+            tracing.gemm_launch(npairs)
             c_hi, c_lo = int8_matmul_nt_streaming_dw(
                 *a_ops, *b_ops, c_hi, c_lo, p_lo=p_lo, t=t, npairs=npairs,
                 scale=2.0 ** (-(t + 2) * w), **kw)
-        return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
+        with tracing.scope(tracing.SCALE_OUT):
+            return DW(ldexp(c_hi, e_base), ldexp(c_lo, e_base))
 
 
 class ModularXlaExecutor:

@@ -69,6 +69,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from .executors import StreamingSplit, get_executor, int32_to_dw
 from .splitting import SplitResult, slice_width
 from .tuning import (BACKENDS, PipelinePlan, TilePlan, diagonal_groups,
@@ -197,6 +198,7 @@ def resolve_accuracy_config(cfg: OzakiConfig, k: int) -> OzakiConfig:
     return dataclasses.replace(cfg, num_splits=s, pair_policy=policy)
 
 
+@tracing.scoped(tracing.EXPONENTS)
 def _e_base(ea: jax.Array, eb: jax.Array) -> jax.Array:
     """Deferred per-element exponent: broadcast outer sum (int32).
 
@@ -210,7 +212,19 @@ def _from_dw(out, cfg: OzakiConfig):
     """df32 accumulator -> the f64 the paper-mode entry points return."""
     if cfg.accum == "f64":
         return out
-    return out.hi.astype(jnp.float64) + out.lo.astype(jnp.float64)
+    with tracing.scope(tracing.SCALE_OUT):
+        return out.hi.astype(jnp.float64) + out.lo.astype(jnp.float64)
+
+
+def _resolve(cfg: OzakiConfig, k: int, batch_layout: str = "none"):
+    """``(cfg, w, executor)`` for one GEMM shape: the accuracy knobs
+    resolved, the slice width, and the executor the plan selects, under
+    the ``repro.plan`` host span."""
+    with tracing.span(tracing.PLAN):
+        tracing.count("plans")
+        cfg = resolve_accuracy_config(cfg, k)
+        w = cfg.width_for(k)
+        return cfg, w, get_executor(cfg.plan(batch_layout=batch_layout))
 
 
 def _check_dw_schedule(cfg: OzakiConfig, w: int) -> None:
@@ -221,22 +235,23 @@ def _check_dw_schedule(cfg: OzakiConfig, w: int) -> None:
 def _fold_rows(split_fn, x3, w: int) -> SplitResult:
     """Split a (B, r, k) stack by folding the batch into rows (exact:
     exponents, slices and accumulation are all row-independent)."""
-    if isinstance(x3, DW):
-        bsz, r, k = x3.hi.shape
-        res = split_fn(DW(x3.hi.reshape(bsz * r, k),
-                          x3.lo.reshape(bsz * r, k)), w)
-    else:
-        bsz, r, k = x3.shape
-        res = split_fn(x3.reshape(bsz * r, k), w)
-    if isinstance(res, StreamingSplit):
-        # nothing was split: un-fold the carried operand words so the
-        # batch-grid streaming kernels see (B, r, k) / (B, r) blocks
-        return StreamingSplit(res.hi.reshape(bsz, r, k),
-                              res.lo.reshape(bsz, r, k),
-                              res.exp.reshape(bsz, r), res.w)
-    s = res.slices.shape[0]
-    return SplitResult(res.slices.reshape(s, bsz, r, k),
-                       res.exp.reshape(bsz, r), res.w)
+    with tracing.scope(tracing.SPLIT):
+        if isinstance(x3, DW):
+            bsz, r, k = x3.hi.shape
+            res = split_fn(DW(x3.hi.reshape(bsz * r, k),
+                              x3.lo.reshape(bsz * r, k)), w)
+        else:
+            bsz, r, k = x3.shape
+            res = split_fn(x3.reshape(bsz * r, k), w)
+        if isinstance(res, StreamingSplit):
+            # nothing was split: un-fold the carried operand words so the
+            # batch-grid streaming kernels see (B, r, k) / (B, r) blocks
+            return StreamingSplit(res.hi.reshape(bsz, r, k),
+                                  res.lo.reshape(bsz, r, k),
+                                  res.exp.reshape(bsz, r), res.w)
+        s = res.slices.shape[0]
+        return SplitResult(res.slices.reshape(s, bsz, r, k),
+                           res.exp.reshape(bsz, r), res.w)
 
 
 # ----------------------------------------------------------------------------
@@ -250,12 +265,11 @@ def ozaki_matmul(a: jax.Array, b: jax.Array,
         raise TypeError("ozaki_matmul takes float64; use ozaki_matmul_dw for "
                         "the TPU df32 path")
     refuse_f64_on_tpu("ozaki_matmul")
-    k = a.shape[1]
-    cfg = resolve_accuracy_config(cfg, k)
-    w = cfg.width_for(k)
-    ex = get_executor(cfg.plan())
+    cfg, w, ex = _resolve(cfg, a.shape[1])
     sa = ex.split(a, w)
-    sb = ex.split(b.T, w)
+    with tracing.scope(tracing.LAYOUT):
+        b_t = b.T
+    sb = ex.split(b_t, w)
     out = ex.contract(sa, sb, w, _e_base(sa.exp, sb.exp),
                       (a.shape[0], b.shape[1]))
     return _from_dw(out, cfg)
@@ -270,11 +284,8 @@ def ozaki_matmul_dw(a: DW, b_t: DW, cfg: OzakiConfig = OzakiConfig()) -> DW:
     """
     if cfg.accum != "df32":
         cfg = dataclasses.replace(cfg, accum="df32")   # dw path IS df32
-    k = a.shape[1]
-    cfg = resolve_accuracy_config(cfg, k)
-    w = cfg.width_for(k)
+    cfg, w, ex = _resolve(cfg, a.shape[1])
     _check_dw_schedule(cfg, w)
-    ex = get_executor(cfg.plan())
     sa = ex.split_dw(a, w)
     sb = ex.split_dw(b_t, w)
     return ex.contract(sa, sb, w, _e_base(sa.exp, sb.exp),
@@ -289,9 +300,11 @@ def _matmul_any(a: jax.Array, b: jax.Array, cfg: OzakiConfig) -> jax.Array:
     """Unbatched dispatch on input dtype: f64 paper path or f32 dw path."""
     if a.dtype == jnp.float64:
         return ozaki_matmul(a, b, cfg)
-    out = ozaki_matmul_dw(DW(a, jnp.zeros_like(a)),
-                          DW(b.T, jnp.zeros_like(b.T)), cfg)
-    return dw_to_single(out)
+    with tracing.scope(tracing.LAYOUT):
+        a, b_t = DW(a, jnp.zeros_like(a)), DW(b.T, jnp.zeros_like(b.T))
+    out = ozaki_matmul_dw(a, b_t, cfg)
+    with tracing.scope(tracing.SCALE_OUT):
+        return dw_to_single(out)
 
 
 def _batched_grid(a: jax.Array, b: jax.Array, cfg: OzakiConfig) -> jax.Array:
@@ -307,22 +320,21 @@ def _batched_grid(a: jax.Array, b: jax.Array, cfg: OzakiConfig) -> jax.Array:
         cfg = dataclasses.replace(cfg, accum="df32")
     bsz, m, k = a.shape
     n = b.shape[-1]
-    cfg = resolve_accuracy_config(cfg, k)
-    w = cfg.width_for(k)
+    cfg, w, ex = _resolve(cfg, k, batch_layout="grid")
     if not f64:
         _check_dw_schedule(cfg, w)
-    ex = get_executor(cfg.plan(batch_layout="grid"))
-    b_t = jnp.swapaxes(b, 1, 2)                        # (B, n, k)
-    if f64:
-        sa = _fold_rows(ex.split, a, w)
-        sb = _fold_rows(ex.split, b_t, w)
-    else:
-        sa = _fold_rows(ex.split_dw, DW(a, jnp.zeros_like(a)), w)
-        sb = _fold_rows(ex.split_dw, DW(b_t, jnp.zeros_like(b_t)), w)
+    with tracing.scope(tracing.LAYOUT):
+        b_t = jnp.swapaxes(b, 1, 2)                    # (B, n, k)
+        if not f64:
+            a, b_t = DW(a, jnp.zeros_like(a)), DW(b_t, jnp.zeros_like(b_t))
+    split = ex.split if f64 else ex.split_dw
+    sa = _fold_rows(split, a, w)
+    sb = _fold_rows(split, b_t, w)
     out = ex.contract(sa, sb, w, _e_base(sa.exp, sb.exp), (bsz, m, n))
     if f64:
         return _from_dw(out, cfg)
-    return dw_to_single(out)
+    with tracing.scope(tracing.SCALE_OUT):
+        return dw_to_single(out)
 
 
 @functools.partial(jax.custom_jvp, nondiff_argnums=(2,))
@@ -332,8 +344,11 @@ def _batched_core(a: jax.Array, b: jax.Array, cfg: OzakiConfig) -> jax.Array:
         # slices and accumulation are all row-independent, so this equals
         # a loop over ``ozaki_matmul`` bitwise (and is one big MXU GEMM).
         bsz, m, k = a.shape
-        out = _matmul_any(a.reshape(bsz * m, k), b, cfg)
-        return out.reshape(bsz, m, b.shape[1])
+        with tracing.scope(tracing.LAYOUT):
+            a = a.reshape(bsz * m, k)
+        out = _matmul_any(a, b, cfg)
+        with tracing.scope(tracing.LAYOUT):
+            return out.reshape(bsz, m, b.shape[1])
     return _batched_grid(a, b, cfg)
 
 
@@ -390,12 +405,10 @@ def ozaki_matmul_complex(a: jax.Array, b: jax.Array,
     exponent range (beyond-paper option).
     """
     refuse_f64_on_tpu("ozaki_matmul_complex")
-    ar, ai = jnp.real(a), jnp.imag(a)
-    br, bi = jnp.real(b), jnp.imag(b)
-    k = a.shape[1]
-    cfg = resolve_accuracy_config(cfg, k)
-    w = cfg.width_for(k)
-    ex = get_executor(cfg.plan())
+    with tracing.scope(tracing.LAYOUT):
+        ar, ai = jnp.real(a), jnp.imag(a)
+        br, bi = jnp.real(b), jnp.imag(b)
+    cfg, w, ex = _resolve(cfg, a.shape[1])
 
     def real_mm(x_split, y_split, shape):
         out = ex.contract(x_split, y_split, w,

@@ -31,6 +31,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from .xmath import DW, exponent, fast_two_sum, ldexp, two_sum
 
 INT8_MIN, INT8_MAX = -128, 127
@@ -84,6 +85,7 @@ def slice_width(k: int, *, ell_acc: int = 31, ell_in: int = 7,
                       ell_in))
 
 
+@tracing.scoped(tracing.EXPONENTS)
 def row_exponents(m: jax.Array) -> jax.Array:
     """Strict power-of-two row exponents: 2**exp > max_j |M_ij| (int32).
 

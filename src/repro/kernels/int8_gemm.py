@@ -88,6 +88,7 @@ def int8_matmul_nt(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         interpret=interpret,
+        name="int8_matmul_nt",
     )(a_p, b_p)
     return out[:m, :n]
 
@@ -136,6 +137,7 @@ def int8_matmul_nt_batched(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda b, i, j, kk: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, mp, np_), jnp.int32),
         interpret=interpret,
+        name="int8_matmul_nt_batched",
     )(a_p, b_p)
     return out[:, :m, :n]
 
@@ -256,7 +258,7 @@ _EPILOGUE_BATCHED = {_epilogue_kernel_sw: _epilogue_kernel_batched_sw,
 
 
 def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
-                     npairs, scale, bm, bn, bk, interpret):
+                     npairs, scale, bm, bn, bk, interpret, name):
     """Shared launch recipe for both epilogue variants, 2-D and batched.
 
     c_arrays: list of (m, n) — or (B, m, n) for (s, B, m, k) slice
@@ -267,7 +269,7 @@ def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
         return _epilogue_launch_batched(
             a_slices, b_slices, c_arrays, _EPILOGUE_BATCHED[kernel],
             p_lo=p_lo, t=t, npairs=npairs, scale=scale, bm=bm, bn=bn,
-            bk=bk, interpret=interpret)
+            bk=bk, interpret=interpret, name=name)
     s, m, k = a_slices.shape
     s2, n, k2 = b_slices.shape
     assert k == k2, (a_slices.shape, b_slices.shape)
@@ -296,12 +298,13 @@ def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={2 + i: i for i in range(nc)},
         interpret=interpret,
+        name=name,
     )(a_p, b_p, *c_p)
     return [o[:m, :n] for o in outs]
 
 
 def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
-                             t, npairs, scale, bm, bn, bk, interpret):
+                             t, npairs, scale, bm, bn, bk, interpret, name):
     """Batch-grid epilogue launch: (s, B, m, k) x (s, B, n, k) slices,
     (B, m, n) carried accumulators, batch outermost in the grid."""
     s, B, m, k = a_slices.shape
@@ -332,6 +335,7 @@ def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={2 + i: i for i in range(nc)},
         interpret=interpret,
+        name=name,
     )(a_p, b_p, *c_p)
     return [o[:, :m, :n] for o in outs]
 
@@ -353,7 +357,8 @@ def int8_matmul_nt_epilogue_sw(a_slices: jax.Array, b_slices: jax.Array,
     assert a_slices.dtype == jnp.int8 and b_slices.dtype == jnp.int8
     (out,) = _epilogue_launch(a_slices, b_slices, [c], _epilogue_kernel_sw,
                               p_lo=p_lo, t=t, npairs=npairs, scale=scale,
-                              bm=bm, bn=bn, bk=bk, interpret=interpret)
+                              bm=bm, bn=bn, bk=bk, interpret=interpret,
+                              name="int8_matmul_nt_epilogue_sw")
     return out
 
 
@@ -377,7 +382,8 @@ def int8_matmul_nt_epilogue_dw(a_slices: jax.Array, b_slices: jax.Array,
     o_hi, o_lo = _epilogue_launch(a_slices, b_slices, [c_hi, c_lo],
                                   _epilogue_kernel_dw, p_lo=p_lo, t=t,
                                   npairs=npairs, scale=scale, bm=bm, bn=bn,
-                                  bk=bk, interpret=interpret)
+                                  bk=bk, interpret=interpret,
+                                  name="int8_matmul_nt_epilogue_dw")
     return o_hi, o_lo
 
 
@@ -525,6 +531,7 @@ def int8_matmul_nt_crt(ra: jax.Array, rb: jax.Array, *, moduli, qmod, inv,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float64),
         scratch_shapes=[pltpu.VMEM((ell, bm_, bn_), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul_nt_crt",
     )(a_p, b_p)
     return out[:m, :n]
 
@@ -557,6 +564,7 @@ def _crt_launch_batched(ra, rb, *, moduli, qmod, inv, scales, bm, bn, bk,
         out_shape=jax.ShapeDtypeStruct((B, mp, np_), jnp.float64),
         scratch_shapes=[pltpu.VMEM((ell, bm_, bn_), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul_nt_crt",
     )(a_p, b_p)
     return out[:, :m, :n]
 
@@ -709,7 +717,7 @@ _STREAMING_BATCHED = {_streaming_kernel_sw: _streaming_kernel_batched_sw,
 
 
 def _streaming_launch(a_ops, b_ops, c_arrays, kernel, *, num_splits, p_lo,
-                      t, npairs, w, scale, bm, bn, bk, interpret):
+                      t, npairs, w, scale, bm, bn, bk, interpret, name):
     """Shared launch recipe for both streaming variants, 2-D and batched.
 
     a_ops/b_ops: (hi, lo, exp) operand triples — (m, k)/(m, k)/(m,) for
@@ -728,7 +736,8 @@ def _streaming_launch(a_ops, b_ops, c_arrays, kernel, *, num_splits, p_lo,
         return _streaming_launch_batched(
             a_ops, b_ops, c_arrays, _STREAMING_BATCHED[kernel],
             ns_a=ns_a, ns_b=ns_b, p_lo=p_lo, t=t, npairs=npairs, w=w,
-            scale=scale, bm=bm, bn=bn, bk=bk, interpret=interpret)
+            scale=scale, bm=bm, bn=bn, bk=bk, interpret=interpret,
+            name=name)
     m, k = a_hi.shape
     n, k2 = b_hi.shape
     assert k == k2, (a_hi.shape, b_hi.shape)
@@ -763,13 +772,14 @@ def _streaming_launch(a_ops, b_ops, c_arrays, kernel, *, num_splits, p_lo,
                         pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={6 + i: i for i in range(nc)},
         interpret=interpret,
+        name=name,
     )(*a_p, *b_p, *c_p)
     return [o[:m, :n] for o in outs]
 
 
 def _streaming_launch_batched(a_ops, b_ops, c_arrays, kernel, *, ns_a, ns_b,
                               p_lo, t, npairs, w, scale, bm, bn, bk,
-                              interpret):
+                              interpret, name):
     """Batch-grid streaming launch: (B, m, k) operand words, (B, m) row
     exponents, (B, m, n) carried accumulators, batch outermost."""
     a_hi, a_lo, a_exp = a_ops
@@ -810,6 +820,7 @@ def _streaming_launch_batched(a_ops, b_ops, c_arrays, kernel, *, ns_a, ns_b,
                         pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={6 + i: i for i in range(nc)},
         interpret=interpret,
+        name=name,
     )(*a_p, *b_p, *c_p)
     return [o[:, :m, :n] for o in outs]
 
@@ -835,7 +846,8 @@ def int8_matmul_nt_streaming_sw(a_hi: jax.Array, a_lo: jax.Array,
                                [c], _streaming_kernel_sw,
                                num_splits=num_splits, p_lo=p_lo, t=t,
                                npairs=npairs, w=w, scale=scale, bm=bm,
-                               bn=bn, bk=bk, interpret=interpret)
+                               bn=bn, bk=bk, interpret=interpret,
+                               name="int8_matmul_nt_streaming_sw")
     return out
 
 
@@ -861,5 +873,6 @@ def int8_matmul_nt_streaming_dw(a_hi: jax.Array, a_lo: jax.Array,
                                    [c_hi, c_lo], _streaming_kernel_dw,
                                    num_splits=num_splits, p_lo=p_lo, t=t,
                                    npairs=npairs, w=w, scale=scale, bm=bm,
-                                   bn=bn, bk=bk, interpret=interpret)
+                                   bn=bn, bk=bk, interpret=interpret,
+                                   name="int8_matmul_nt_streaming_dw")
     return o_hi, o_lo

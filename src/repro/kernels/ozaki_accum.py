@@ -94,6 +94,7 @@ def accum_scaled_dw(p: jax.Array, c_hi: jax.Array, c_lo: jax.Array, *,
                    jax.ShapeDtypeStruct((mp, np_), jnp.float32)],
         input_output_aliases={1: 0, 2: 1},
         interpret=interpret,
+        name="accum_scaled_dw",
     )(p, c_hi, c_lo)
     return o_hi[:m, :n], o_lo[:m, :n]
 
@@ -128,5 +129,6 @@ def accum_scaled_sw(p: jax.Array, c: jax.Array, *, scale: float,
         out_shape=jax.ShapeDtypeStruct((mp, np_), c.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="accum_scaled_sw",
     )(p, c)
     return out[:m, :n]

@@ -101,5 +101,6 @@ def fused_split_dw(hi: jax.Array, lo: jax.Array, exp: jax.Array, *,
                                lambda i, j: (jnp.int32(0), i, j)),
         out_shape=jax.ShapeDtypeStruct((num_splits, mp, kp), jnp.int8),
         interpret=interpret,
+        name="fused_split_dw",
     )(hi, lo, exp)
     return out[:, :m, :k]

@@ -56,16 +56,17 @@ def traced_window(bench, name: str, seed: int, seconds: float,
 
     The loop is ``run_cell``'s, less its timing and the check: the same
     operands, the same sample index sets rotating (call i of a product
-    samples with set i mod ``POOL``), the same spans, and Python's cyclic
-    collector off in the window. ``tests/chipbench`` holds the two loops
-    to the same sequence of calls."""
+    samples with set i mod ``POOL``), the same samples held (``Held``),
+    the same spans, and Python's cyclic collector off in the window.
+    ``tests/chipbench`` holds the two loops to the same sequence of
+    calls."""
     import gc
 
     import jax
     import numpy as np
     from repro import tracing
 
-    from benchmarks.chip.harness import (POOL, _product, _shape_of,
+    from benchmarks.chip.harness import (POOL, Held, _product, _shape_of,
                                          chips_for)
     from benchmarks.chip.operands import make_operands
 
@@ -111,8 +112,8 @@ def traced_window(bench, name: str, seed: int, seconds: float,
     shutil.rmtree(trace_dir, ignore_errors=True)
     before = tracing.counters()
     jax.profiler.start_trace(trace_dir)
-    # the samples stay held, as the harness holds them for its check
-    i, samples = 0, []
+    # the samples are held as the harness holds them for its check
+    i, held = 0, Held(int(chk["calls"]), seed)
     gc.collect()
     gc.disable()
     try:
@@ -127,7 +128,7 @@ def traced_window(bench, name: str, seed: int, seconds: float,
                 sample = route.sample(out, dev_idx[j][s])
             with jax.profiler.TraceAnnotation("wait"):
                 jax.block_until_ready((out, sample))
-            samples.append(sample)
+            held.add(sample)
             del out, sample
             i += 1
             if time.perf_counter() >= deadline and i >= int(chk["calls"]):

@@ -188,6 +188,42 @@ class Route:
     word_bytes: int
 
 
+class Held:
+    """The check's samples held through a window: the newest call's, and
+    a reservoir (Algorithm R, seeded) of ``calls - 1`` of the calls
+    before it, so that at most ``calls`` stay on the device however many
+    calls the window runs. The check reads them all once the window has
+    closed: a uniform sample of the earlier calls, and the last call.
+
+    ``add`` is host bookkeeping alone: a sample the reservoir does not
+    keep is dropped at once, and the device frees it."""
+
+    def __init__(self, calls: int, seed: int):
+        self.slots = calls - 1
+        self.rng = np.random.default_rng([seed & (2 ** 63 - 1), 2])
+        self.reservoir: list = []        # (call, sample) of earlier calls
+        self.newest: Optional[tuple] = None
+        self.count = 0
+
+    def add(self, sample) -> None:
+        """Hold call ``count``'s sample as the newest, and offer the one
+        it follows, call ``count - 1``, to the reservoir."""
+        i, offered = self.count, self.newest
+        if offered is not None:
+            if len(self.reservoir) < self.slots:
+                self.reservoir.append(offered)
+            else:
+                r = int(self.rng.integers(0, i))
+                if r < self.slots:
+                    self.reservoir[r] = offered
+        self.newest = (i, sample)
+        self.count += 1
+
+    def picked(self) -> list:
+        """``(call, sample)`` of every held call, in call order."""
+        return sorted(self.reservoir + [self.newest], key=lambda p: p[0])
+
+
 def copy_sampler(devices):
     """``(index, sample)`` for a route whose output has a copy on each of
     ``devices``: ``index(rows, cols)`` places a sample's indices on every
@@ -350,8 +386,9 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
 
-    times, starts, samples, which = [], [], [], []
+    times, starts, which = [], [], []
     min_calls = int(chk["calls"])
+    held = Held(min_calls, seed)
     i = 0
     # Python's cyclic collector off in the window, as ``timeit`` does: on
     # a one-chip machine its passes stalled calls by 0.1-2.3 s, 1-5 times
@@ -368,8 +405,8 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("call"):
                 out = call(ops[lhs], ops[rhs])
-            # the sample for the check is queued behind the call and read
-            # once the window has closed
+            # the sample for the check is queued behind the call; those
+            # held are read once the window has closed
             with jax.profiler.TraceAnnotation("check"):
                 sample = route.sample(out, dev_idx[j][s])
             with jax.profiler.TraceAnnotation("wait"):
@@ -377,7 +414,7 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
             t2 = time.perf_counter()
             times.append(t2 - t1)
             starts.append(t1)
-            samples.append(sample)
+            held.add(sample)
             which.append((j, s))
             del out, sample
             i += 1
@@ -396,8 +433,8 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float,
     temps = _temp_bytes(route, ops, [cycle[j] for j in warm])
     analysis_s = time.perf_counter() - t
     memory_peak = in_use + temps
-    pulled = _pull(cell, ops, cycle, host_idx, samples, which, seed)
-    del ops, arrays, samples            # the program's state, freed
+    pulled = _pull(ops, cycle, host_idx, held.picked(), which)
+    del ops, arrays, held               # the program's state, freed
     checks, rms = _compare(cell, route.copies, *pulled)
     reduced = None
     if trace:
@@ -461,18 +498,10 @@ def _stalls(times: list, starts: list) -> dict:
             "between_total_s": sum(between)}
 
 
-def _pull(cell: Cell, ops: dict, cycle, host_idx, samples, which,
-          seed: int):
-    """Bring a seeded sample of the window's calls to the host: for each,
-    its operand rows and columns and every copy of its output block. The
-    last call is always among them."""
+def _pull(ops: dict, cycle, host_idx, picked, which):
+    """Bring the held calls (``Held.picked()``) to the host: for each,
+    its operand rows and columns and every copy of its output block."""
     import jax
-
-    n = len(samples)
-    rng = np.random.default_rng([seed & (2 ** 63 - 1), 2])
-    want = min(int(cell.traffic["check"]["calls"]), n)
-    picked = sorted(set(rng.choice(n - 1, want - 1, replace=False).tolist())
-                    | {n - 1}) if n > 1 else [0]
 
     @jax.jit
     def take_rows(x, rows):
@@ -488,7 +517,7 @@ def _pull(cell: Cell, ops: dict, cycle, host_idx, samples, which,
         return np.asarray(x).astype(np.float64)
 
     a_blocks, b_blocks, c_blocks = [], [], []
-    for i in picked:
+    for i, sample in picked:
         j, s = which[i]
         rows, cols = host_idx[j][s]
         lhs, rhs = cycle[j]
@@ -496,7 +525,7 @@ def _pull(cell: Cell, ops: dict, cycle, host_idx, samples, which,
         b_blocks.append(host_f64(take_cols(ops[rhs], cols)))
         c_blocks.append([np.asarray(hi).astype(np.float64)
                          + np.asarray(lo).astype(np.float64)
-                         for hi, lo in samples[i]])
+                         for hi, lo in sample])
     return a_blocks, b_blocks, c_blocks
 
 

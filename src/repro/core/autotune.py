@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import tempfile
@@ -401,14 +402,14 @@ def candidate_plans(m: int, n: int, k: int, *, batch: int = 1,
     trusting the GEMM-count model. Both seeds guarantee the same target,
     so any winner honors the contract.
     """
-    base = select_pipeline_plan(
-        m, n, k, batch=batch, broadcast_weights=broadcast_weights,
-        backend=backend, accum=accum, num_splits=num_splits,
-        fuse_epilogue=fuse_epilogue, streaming=streaming,
-        shard_axis=shard_axis, comm=comm,
+    analytic = functools.partial(
+        select_pipeline_plan, m, n, k, batch=batch,
+        broadcast_weights=broadcast_weights, backend=backend, accum=accum,
+        num_splits=num_splits, shard_axis=shard_axis, comm=comm,
         interpret=interpret, target_error=target_error,
         fast_mode=fast_mode, pair_policy=pair_policy, scheme=scheme,
         num_moduli=num_moduli, **analytic_kwargs)
+    base = analytic(fuse_epilogue=fuse_epilogue, streaming=streaming)
     cands = [base]
 
     def add(plan: PipelinePlan):
@@ -454,10 +455,14 @@ def candidate_plans(m: int, n: int, k: int, *, batch: int = 1,
     # fusion-mode flips (pallas_fused only; all modes bitwise-equal —
     # streaming included, so the measurement decides whether eliminating
     # the HBM slice stacks beats re-reading the operand words per group)
+    # Each flip takes the GEMM blocks planned for its own kernel family:
+    # the epilogue's outgrow what the other families' VMEM holds.
     if base.fusion in ("stages", "epilogue", "streaming"):
         for flip in ("stages", "epilogue", "streaming"):
             if flip != base.fusion:
-                add(dataclasses.replace(base, fusion=flip))
+                tile = analytic(fuse_epilogue=flip == "epilogue",
+                                streaming=flip == "streaming").tile
+                add(dataclasses.replace(base, fusion=flip, tile=tile))
 
     # comm-transport flip (sharded shapes only): both transports are
     # bitwise-equal to the single-device reference (integer collectives

@@ -34,6 +34,7 @@ importable without ``repro.kernels`` and cycle-free.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -260,14 +261,23 @@ class EpilogueExecutor(FusedExecutor):
                  e_base: jax.Array, shape):
         from repro.kernels import (int8_matmul_nt_epilogue_dw,
                                    int8_matmul_nt_epilogue_sw)
+        from repro.kernels.int8_gemm import epilogue_grid
         assert len(shape) in (2, 3), shape    # 3-D: batch-grid kernels
         tile = self.plan.tile
-        kw = dict(bm=tile.bm, bn=tile.bn, bk=tile.bk,
-                  interpret=self.plan.interpret)
+        blocks = dict(bm=tile.bm, bn=tile.bn, bk=tile.bk)
+        kw = dict(blocks, interpret=self.plan.interpret)
+        planes, el_bytes = (1, 8) if self.plan.accum == "f64" else (2, 4)
+
+        def launch(npairs):
+            _, grid = epilogue_grid(sa.slices.shape, sb.slices.shape, npairs,
+                                    planes=planes, el_bytes=el_bytes,
+                                    **blocks)
+            tracing.gemm_launch(npairs, grid_steps=math.prod(grid))
+
         if self.plan.accum == "f64":
             c = jnp.zeros(shape, jnp.float64)
             for t, p_lo, npairs in self._groups():
-                tracing.gemm_launch(npairs)
+                launch(npairs)
                 c = int8_matmul_nt_epilogue_sw(
                     sa.slices, sb.slices, c, p_lo=p_lo, t=t, npairs=npairs,
                     scale=2.0 ** (-(t + 2) * w), **kw)
@@ -276,7 +286,7 @@ class EpilogueExecutor(FusedExecutor):
         c_hi = jnp.zeros(shape, jnp.float32)
         c_lo = jnp.zeros(shape, jnp.float32)
         for t, p_lo, npairs in self._groups():
-            tracing.gemm_launch(npairs)
+            launch(npairs)
             c_hi, c_lo = int8_matmul_nt_epilogue_dw(
                 sa.slices, sb.slices, c_hi, c_lo, p_lo=p_lo, t=t,
                 npairs=npairs, scale=2.0 ** (-(t + 2) * w), **kw)

@@ -63,8 +63,9 @@ from typing import Optional, Sequence
 # the planner's choices match shrink_block's exactly (repro.core imports
 # repro.kernels.launch only — the kernels themselves import repro.core
 # lazily, so there is no cycle).
-from repro.kernels.launch import (LANE, SUBLANE_F32 as SUBLANE, SUBLANE_I8,
-                                  VMEM_BYTES, align_up as _align_up)
+from repro.kernels.launch import (GEMM_BLOCKS, LANE, SUBLANE_F32 as SUBLANE,
+                                  SUBLANE_I8, VMEM_BYTES,
+                                  align_up as _align_up, epilogue_blocks)
 
 from .analytic import DGEMM_MANTISSA_SPACE, INT8_INT32, MMUSpec
 from .splitting import slice_width
@@ -134,11 +135,20 @@ def reset_downgrade_warnings() -> None:
 
 @dataclasses.dataclass(frozen=True)
 class TilePlan:
-    """Block shapes + schedule for one fused-pipeline launch (hashable)."""
+    """Block shapes + schedule for one fused-pipeline launch (hashable).
 
-    bm: int = 256                   # int8 GEMM output rows per block
-    bn: int = 256                   # int8 GEMM output cols per block
-    bk: int = 512                   # int8 GEMM reduction slab
+    The GEMM blocks are requests, which a kernel's launch recipe
+    (``kernels/launch.py``) only shrinks; ``None`` requests nothing and
+    leaves the blocks to the recipe of the GEMM family that runs them:
+    ``GEMM_BLOCKS`` (256 x 256 x 512) for the stage-fused, streaming and
+    fused-CRT kernels, the VMEM model of ``epilogue_blocks`` for the
+    epilogue-fused ones. The kernel wrappers' defaults are the same
+    ``None``, so ``TilePlan()`` launches what a bare kernel call does.
+    """
+
+    bm: Optional[int] = None        # int8 GEMM output rows per block
+    bn: Optional[int] = None        # int8 GEMM output cols per block
+    bk: Optional[int] = None        # int8 GEMM reduction slab
     split_bm: int = 256             # split kernel input tile rows
     split_bk: int = 256             # split kernel input tile cols
     accum_bm: int = 256             # accumulation tile rows
@@ -176,12 +186,13 @@ def select_plan(m: int, n: int, k: int, *, batch: int = 1,
         num_splits = select_num_splits(k, mantissa_space=mantissa_space,
                                        mmu=mmu)
 
-    # --- GEMM blocks: shrink from the 256x256x512 MXU sweet spot.
+    # --- GEMM blocks: shrink from the GEMM family's GEMM_BLOCKS.
     # bm is an int8 A-tile sublane dim (32-aligned); bn doubles as the
     # int32 C-tile lane dim, so the stricter 128 alignment applies.
-    bm = min(256, _pow2_at_most(_align_up(m, SUBLANE_I8), SUBLANE_I8))
-    bn = min(256, _pow2_at_most(_align_up(n, LANE), LANE))
-    bk = min(512, _pow2_at_most(_align_up(k, LANE), LANE))
+    cap_m, cap_n, cap_k = GEMM_BLOCKS
+    bm = min(cap_m, _pow2_at_most(_align_up(m, SUBLANE_I8), SUBLANE_I8))
+    bn = min(cap_n, _pow2_at_most(_align_up(n, LANE), LANE))
+    bk = min(cap_k, _pow2_at_most(_align_up(k, LANE), LANE))
     while bm * bk + bn * bk + 4 * bm * bn > vmem_budget:
         if bk > LANE:
             bk //= 2
@@ -209,6 +220,19 @@ def select_plan(m: int, n: int, k: int, *, batch: int = 1,
                     split_bk=split_bk, accum_bm=accum_bm, accum_bn=accum_bn,
                     num_splits=num_splits, fuse_diagonals=True,
                     concat_k=(k <= CONCAT_K_MAX and batch == 1))
+
+
+def _epilogue_tile(tile: TilePlan, m: int, n: int, k: int, *, accum: str,
+                  request: bool = False) -> TilePlan:
+    """``tile`` with the GEMM blocks an epilogue-fused launch takes at
+    this shape: ``epilogue_blocks`` for the accumulator planes ``accum``
+    carries (two float32 for df32, one float64 for f64), capped by the
+    tile's own blocks where they are a ``request``."""
+    planes, el_bytes = (1, 8) if accum == "f64" else (2, 4)
+    req = (tile.bm, tile.bn, tile.bk) if request else (None, None, None)
+    bm, bn, bk = epilogue_blocks(m, n, k, *req, planes=planes,
+                                 el_bytes=el_bytes)
+    return dataclasses.replace(tile, bm=bm, bn=bn, bk=bk)
 
 
 def apply_plan(cfg, plan: TilePlan):
@@ -469,9 +493,9 @@ def _fusion_for(backend: str, fuse_epilogue: bool, batch_layout: str,
 def plan_for(cfg, *, batch_layout: str = "none") -> PipelinePlan:
     """Reflect an ``OzakiConfig`` (duck-typed) into a ``PipelinePlan``.
 
-    ``cfg.tile=None`` keeps the kernels' MXU-aligned default blocks
-    (``TilePlan()`` matches the kernel defaults exactly); schedule flags
-    come from the config, never from the tile.
+    ``cfg.tile=None`` requests no GEMM blocks (``TilePlan()``), so each
+    kernel family's launch recipe sizes its own; schedule flags come
+    from the config, never from the tile.
     """
     tile = cfg.tile if cfg.tile is not None else TilePlan(
         num_splits=cfg.num_splits, fuse_diagonals=cfg.fuse_diagonals,
@@ -532,7 +556,7 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
                          pair_policy: Optional[str] = None,
                          mantissa_space: int = DGEMM_MANTISSA_SPACE,
                          mmu: MMUSpec = INT8_INT32,
-                         vmem_budget: int = VMEM_BUDGET,
+                         vmem_budget: Optional[int] = None,
                          cache=None, autotune: bool = False,
                          dtype: Optional[str] = None,
                          device_kind: Optional[str] = None,
@@ -569,9 +593,16 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
     point (``core.modular.resolve_modular``), the plan cache is keyed
     with the scheme, and fast-mode/pair-policy knobs are rejected (the
     residue path has no pair schedule).
+
+    GEMM blocks: ``select_plan``'s, except that an epilogue-fused plan
+    takes the epilogue family's own (``_epilogue_tile``). A
+    ``vmem_budget`` given here is a request on every family: it sizes
+    ``select_plan``'s blocks (``VMEM_BUDGET`` when None), and they then
+    cap the epilogue blocks too.
     """
     from repro.kernels.launch import resolve_interpret
     interpret = resolve_interpret(interpret)
+    budget = VMEM_BUDGET if vmem_budget is None else vmem_budget
     if batch <= 1 and not broadcast_weights:
         layout = "none"
     elif broadcast_weights:
@@ -627,7 +658,7 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
                            batch=batch if layout == "grid" else 1,
                            num_splits=point.num_splits,
                            mantissa_space=mantissa_space, mmu=mmu,
-                           vmem_budget=vmem_budget)
+                           vmem_budget=budget)
         return modular_plan(k, point=point, backend=backend,
                             fuse_epilogue=(fuse_epilogue and
                                            backend == "pallas_fused"),
@@ -682,11 +713,14 @@ def select_pipeline_plan(m: int, n: int, k: int, *, batch: int = 1,
     m_eff = m * batch if layout == "rows" else m
     tile = select_plan(m_eff, n, k, batch=batch if layout == "grid" else 1,
                        num_splits=num_splits, mantissa_space=mantissa_space,
-                       mmu=mmu, vmem_budget=vmem_budget)
+                       mmu=mmu, vmem_budget=budget)
+    fusion = _fusion_for(backend, fuse_epilogue, layout, streaming=streaming)
+    if fusion == "epilogue":
+        tile = _epilogue_tile(tile, m_eff, n, k, accum=accum,
+                             request=vmem_budget is not None)
     return PipelinePlan(
         num_splits=tile.num_splits, tile=tile, backend=backend,
-        fusion=_fusion_for(backend, fuse_epilogue, layout,
-                           streaming=streaming),
+        fusion=fusion,
         batch_layout=layout, shard_axis=shard_axis, comm=comm,
         pair_policy=policy,
         fuse_diagonals=tile.fuse_diagonals, concat_k=tile.concat_k,

@@ -7,9 +7,13 @@ no transposition between HBM and VMEM.
 
 Tiling: grid (m/bm, n/bn, k/bk), k innermost so each output block stays
 resident in VMEM while the k loop streams A/B tiles through the MXU,
-accumulating in int32. Block shapes default to MXU-aligned 256x256x512:
-  A tile 256x512 int8 = 128 KiB, B tile 256x512 int8 = 128 KiB,
-  C tile 256x256 int32 = 256 KiB  ->  ~0.5 MiB VMEM of ~16 MiB.
+accumulating in int32. With no block requested, ``int8_matmul_nt`` runs
+``launch.GEMM_BLOCKS``, MXU-aligned 256x256x512: A and B tiles of 128 KiB
+and a 256 KiB int32 C tile, about 1 MiB with double buffering. The
+epilogue-fused variants size their blocks from a VMEM working-set model
+instead (``launch.epilogue_blocks``): at 16384^2 outputs a 1024x1024 C
+tile with its two df32 planes, in and out and double-buffered, is 32 MiB,
+so those launches declare a scoped VMEM limit above Mosaic's default.
 
 ``int8_matmul_nt_batched`` adds a leading batch grid dimension — one
 kernel launch for a whole ``(B, m, k) x (B, n, k)`` stack (the batched
@@ -43,7 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .launch import (crt_blocks, gemm_blocks, grid_for, kernel_jit, pad_tail,
+from .launch import (crt_blocks, epilogue_blocks, epilogue_vmem_limit,
+                     gemm_blocks, grid_for, kernel_jit, pad_tail,
                      streaming_blocks)
 from .ozaki_accum import dw_accum_step
 from .ozaki_split import split_tile
@@ -64,8 +69,8 @@ def _kernel(a_ref, b_ref, o_ref):
 
 
 @kernel_jit(static_argnames=("bm", "bn", "bk", "interpret"))
-def int8_matmul_nt(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
-                   bn: int = 256, bk: int = 512,
+def int8_matmul_nt(a: jax.Array, b_t: jax.Array, *, bm: Optional[int] = None,
+                   bn: Optional[int] = None, bk: Optional[int] = None,
                    interpret: Optional[bool] = None) -> jax.Array:
     """C = A @ B_t.T with int32 accumulation. a: (m, k) int8, b_t: (n, k)."""
     assert a.dtype == jnp.int8 and b_t.dtype == jnp.int8
@@ -108,8 +113,10 @@ def _kernel_batched(a_ref, b_ref, o_ref):
 
 
 @kernel_jit(static_argnames=("bm", "bn", "bk", "interpret"))
-def int8_matmul_nt_batched(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
-                           bn: int = 256, bk: int = 512,
+def int8_matmul_nt_batched(a: jax.Array, b_t: jax.Array, *,
+                           bm: Optional[int] = None,
+                           bn: Optional[int] = None,
+                           bk: Optional[int] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """C[b] = A[b] @ B_t[b].T for every batch row, one kernel launch.
 
@@ -165,96 +172,142 @@ def int8_matmul_nt_batched(a: jax.Array, b_t: jax.Array, *, bm: int = 256,
 # falling back to the stage-fused pipeline (the PR 2 limitation).
 
 
+# The kernel bodies run in pieces, in loops: the product over K in chunks
+# of at most EPILOGUE_K_CHUNK, the epilogue over strips of at most
+# EPILOGUE_ROW_STRIP rows. Each of a call's nine launches compiles to its
+# own kernel, whose code sits in HBM beside the data; unrolled over a
+# 1024 x 1024 x 4096 block it was 3.6 MB a launch (compiled for a v5e),
+# in pieces 0.65 MB, whatever the blocks.
+EPILOGUE_K_CHUNK = 512
+EPILOGUE_ROW_STRIP = 128
+
+
+def _in_pieces(extent: int, cap: int, body) -> None:
+    """``body(ds)`` over ``extent`` in pieces of the largest power of two
+    that divides it, at most ``cap``."""
+    size = math.gcd(extent, cap)
+
+    def piece(i, carry):
+        body(pl.ds(pl.multiple_of(i * size, size), size))
+        return carry
+
+    # int32 bounds: an int64 index (x64 on) fails Mosaic's verifier
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(extent // size), piece, 0)
+
+
+def _accumulate(lead, a_ref, b_ref, acc_ref, first):
+    """acc += A @ B.T over this block's K, one chunk at a time, from zero
+    at the ``first`` grid step; ``lead`` indexes the operand blocks'
+    leading (pair, batch) dims."""
+
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(ks):
+        acc_ref[...] += jax.lax.dot_general(
+            a_ref[lead + (slice(None), ks)], b_ref[lead + (slice(None), ks)],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32)
+
+    _in_pieces(a_ref.shape[-1], EPILOGUE_K_CHUNK, chunk)
+
+
 def _epilogue_kernel_sw(scale, npairs, nk, a_ref, b_ref, c_ref, o_ref,
                         acc_ref):
     pp = pl.program_id(2)
     kk = pl.program_id(3)
-
-    @pl.when((pp == 0) & (kk == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        a_ref[0], b_ref[0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    _accumulate((0,), a_ref, b_ref, acc_ref, (pp == 0) & (kk == 0))
 
     @pl.when((pp == npairs - 1) & (kk == nk - 1))
     def _epilogue():
-        c = c_ref[...]
-        # int32 -> f64 exact; scale an exact power of two: ONE rounding,
-        # matching ``_accum_f64`` / ``accum_scaled_sw`` bitwise.
-        o_ref[...] = c + acc_ref[...].astype(c.dtype) * jnp.asarray(
-            scale, c.dtype)
+        def strip(rows):
+            c = c_ref[rows, :]
+            # int32 -> f64 exact; scale an exact power of two: ONE
+            # rounding, matching ``_accum_f64`` / ``accum_scaled_sw``
+            # bitwise.
+            o_ref[rows, :] = c + acc_ref[rows, :].astype(c.dtype) * \
+                jnp.asarray(scale, c.dtype)
+
+        _in_pieces(acc_ref.shape[0], EPILOGUE_ROW_STRIP, strip)
 
 
 def _epilogue_kernel_dw(scale, npairs, nk, a_ref, b_ref, chi_ref, clo_ref,
                         ohi_ref, olo_ref, acc_ref):
     pp = pl.program_id(2)
     kk = pl.program_id(3)
-
-    @pl.when((pp == 0) & (kk == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        a_ref[0], b_ref[0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    _accumulate((0,), a_ref, b_ref, acc_ref, (pp == 0) & (kk == 0))
 
     @pl.when((pp == npairs - 1) & (kk == nk - 1))
     def _epilogue():
-        n_hi, n_lo = dw_accum_step(acc_ref[...], chi_ref[...], clo_ref[...],
-                                   scale)
-        ohi_ref[...] = n_hi
-        olo_ref[...] = n_lo
+        def strip(rows):
+            n_hi, n_lo = dw_accum_step(acc_ref[rows, :], chi_ref[rows, :],
+                                       clo_ref[rows, :], scale)
+            ohi_ref[rows, :] = n_hi
+            olo_ref[rows, :] = n_lo
+
+        _in_pieces(acc_ref.shape[0], EPILOGUE_ROW_STRIP, strip)
 
 
 def _epilogue_kernel_batched_sw(scale, npairs, nk, a_ref, b_ref, c_ref,
                                 o_ref, acc_ref):
     pp = pl.program_id(3)
     kk = pl.program_id(4)
-
-    @pl.when((pp == 0) & (kk == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        a_ref[0, 0], b_ref[0, 0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    _accumulate((0, 0), a_ref, b_ref, acc_ref, (pp == 0) & (kk == 0))
 
     @pl.when((pp == npairs - 1) & (kk == nk - 1))
     def _epilogue():
-        c = c_ref[0]
-        o_ref[...] = (c + acc_ref[...].astype(c.dtype) * jnp.asarray(
-            scale, c.dtype))[None]
+        def strip(rows):
+            c = c_ref[0, rows, :]
+            o_ref[0, rows, :] = c + acc_ref[rows, :].astype(c.dtype) * \
+                jnp.asarray(scale, c.dtype)
+
+        _in_pieces(acc_ref.shape[0], EPILOGUE_ROW_STRIP, strip)
 
 
 def _epilogue_kernel_batched_dw(scale, npairs, nk, a_ref, b_ref, chi_ref,
                                 clo_ref, ohi_ref, olo_ref, acc_ref):
     pp = pl.program_id(3)
     kk = pl.program_id(4)
-
-    @pl.when((pp == 0) & (kk == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        a_ref[0, 0], b_ref[0, 0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    _accumulate((0, 0), a_ref, b_ref, acc_ref, (pp == 0) & (kk == 0))
 
     @pl.when((pp == npairs - 1) & (kk == nk - 1))
     def _epilogue():
-        n_hi, n_lo = dw_accum_step(acc_ref[...], chi_ref[0], clo_ref[0],
-                                   scale)
-        ohi_ref[...] = n_hi[None]
-        olo_ref[...] = n_lo[None]
+        def strip(rows):
+            n_hi, n_lo = dw_accum_step(acc_ref[rows, :], chi_ref[0, rows, :],
+                                       clo_ref[0, rows, :], scale)
+            ohi_ref[0, rows, :] = n_hi
+            olo_ref[0, rows, :] = n_lo
+
+        _in_pieces(acc_ref.shape[0], EPILOGUE_ROW_STRIP, strip)
 
 
 _EPILOGUE_BATCHED = {_epilogue_kernel_sw: _epilogue_kernel_batched_sw,
                      _epilogue_kernel_dw: _epilogue_kernel_batched_dw}
+
+
+def epilogue_grid(a_shape, b_shape, npairs: int, *, planes: int,
+                  el_bytes: int, bm: Optional[int] = None,
+                  bn: Optional[int] = None, bk: Optional[int] = None):
+    """(blocks, grid) of one epilogue launch over (s, m, k) x (s, n, k)
+    slice stacks carrying ``planes`` accumulator planes of ``el_bytes``
+    each: blocks from ``launch.epilogue_blocks``, grid (m/bm, n/bn,
+    npairs, k/bk), with the batch first for (s, B, m, k) stacks."""
+    *batch, m, k = a_shape[1:]
+    n = b_shape[-2]
+    blocks = epilogue_blocks(m, n, k, bm, bn, bk, planes=planes,
+                             el_bytes=el_bytes)
+    gm, gn, gk = (-(-d // b) for d, b in zip((m, n, k), blocks))
+    return blocks, (*batch, gm, gn, npairs, gk)
+
+
+def _epilogue_params(blocks, c_arrays):
+    """Mosaic parameters of an epilogue launch: the scoped VMEM limit its
+    blocks were sized to, where that is above the default."""
+    limit = epilogue_vmem_limit(*blocks, planes=len(c_arrays),
+                                el_bytes=c_arrays[0].dtype.itemsize)
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
@@ -275,7 +328,11 @@ def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
     assert k == k2, (a_slices.shape, b_slices.shape)
     assert 0 <= p_lo and p_lo + npairs <= s, (p_lo, npairs, s)
     assert 0 <= t - p_lo - (npairs - 1) and t - p_lo < s2, (p_lo, t, npairs)
-    bm_, bn_, bk_ = gemm_blocks(m, n, k, bm, bn, bk)
+    blocks, _ = epilogue_grid(a_slices.shape, b_slices.shape, npairs,
+                              planes=len(c_arrays),
+                              el_bytes=c_arrays[0].dtype.itemsize, bm=bm,
+                              bn=bn, bk=bk)
+    bm_, bn_, bk_ = blocks
     a_p = pad_tail(a_slices, (bm_, bk_))
     b_p = pad_tail(b_slices, (bn_, bk_))
     c_p = [pad_tail(c, (bm_, bn_)) for c in c_arrays]
@@ -297,6 +354,7 @@ def _epilogue_launch(a_slices, b_slices, c_arrays, kernel, *, p_lo, t,
         out_shape=[jax.ShapeDtypeStruct((mp, np_), c.dtype) for c in c_p],
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={2 + i: i for i in range(nc)},
+        compiler_params=_epilogue_params(blocks, c_arrays),
         interpret=interpret,
         name=name,
     )(a_p, b_p, *c_p)
@@ -312,7 +370,11 @@ def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
     assert k == k2 and B == B2, (a_slices.shape, b_slices.shape)
     assert 0 <= p_lo and p_lo + npairs <= s, (p_lo, npairs, s)
     assert 0 <= t - p_lo - (npairs - 1) and t - p_lo < s2, (p_lo, t, npairs)
-    bm_, bn_, bk_ = gemm_blocks(m, n, k, bm, bn, bk)
+    blocks, _ = epilogue_grid(a_slices.shape, b_slices.shape, npairs,
+                              planes=len(c_arrays),
+                              el_bytes=c_arrays[0].dtype.itemsize, bm=bm,
+                              bn=bn, bk=bk)
+    bm_, bn_, bk_ = blocks
     a_p = pad_tail(a_slices, (bm_, bk_))
     b_p = pad_tail(b_slices, (bn_, bk_))
     c_p = [pad_tail(c, (bm_, bn_)) for c in c_arrays]
@@ -334,6 +396,7 @@ def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
         out_shape=[jax.ShapeDtypeStruct((B, mp, np_), c.dtype) for c in c_p],
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
         input_output_aliases={2 + i: i for i in range(nc)},
+        compiler_params=_epilogue_params(blocks, c_arrays),
         interpret=interpret,
         name=name,
     )(a_p, b_p, *c_p)
@@ -344,8 +407,10 @@ def _epilogue_launch_batched(a_slices, b_slices, c_arrays, kernel, *, p_lo,
                              "bk", "interpret"))
 def int8_matmul_nt_epilogue_sw(a_slices: jax.Array, b_slices: jax.Array,
                                c: jax.Array, *, p_lo: int, t: int,
-                               npairs: int, scale: float, bm: int = 256,
-                               bn: int = 256, bk: int = 512,
+                               npairs: int, scale: float,
+                               bm: Optional[int] = None,
+                               bn: Optional[int] = None,
+                               bk: Optional[int] = None,
                                interpret: Optional[bool] = None) -> jax.Array:
     """c += (sum_pp A[p_lo+pp] @ B[t-p_lo-pp].T) * scale, epilogue-fused.
 
@@ -367,7 +432,9 @@ def int8_matmul_nt_epilogue_sw(a_slices: jax.Array, b_slices: jax.Array,
 def int8_matmul_nt_epilogue_dw(a_slices: jax.Array, b_slices: jax.Array,
                                c_hi: jax.Array, c_lo: jax.Array, *,
                                p_lo: int, t: int, npairs: int, scale: float,
-                               bm: int = 256, bn: int = 256, bk: int = 512,
+                               bm: Optional[int] = None,
+                               bn: Optional[int] = None,
+                               bk: Optional[int] = None,
                                interpret: Optional[bool] = None
                                ) -> tuple[jax.Array, jax.Array]:
     """(c_hi, c_lo) += df32(group product) * scale, epilogue-fused.
@@ -488,7 +555,8 @@ def _crt_kernel_batched(moduli, qmod, inv, scales, nk, a_ref, b_ref, o_ref,
 @kernel_jit(static_argnames=("moduli", "qmod", "inv", "scales", "bm", "bn",
                              "bk", "interpret"))
 def int8_matmul_nt_crt(ra: jax.Array, rb: jax.Array, *, moduli, qmod, inv,
-                       scales, bm: int = 256, bn: int = 256, bk: int = 512,
+                       scales, bm: Optional[int] = None,
+                       bn: Optional[int] = None, bk: Optional[int] = None,
                        interpret: Optional[bool] = None) -> jax.Array:
     """Fused residue GEMMs + balanced-Garner CRT reconstruction.
 
@@ -832,7 +900,9 @@ def int8_matmul_nt_streaming_sw(a_hi: jax.Array, a_lo: jax.Array,
                                 b_lo: jax.Array, b_exp: jax.Array,
                                 c: jax.Array, *, num_splits: int, p_lo: int,
                                 t: int, npairs: int, w: int, scale: float,
-                                bm: int = 256, bn: int = 256, bk: int = 512,
+                                bm: Optional[int] = None,
+                                bn: Optional[int] = None,
+                                bk: Optional[int] = None,
                                 interpret: Optional[bool] = None) -> jax.Array:
     """c += (sum_pp A[p_lo+pp] @ B[t-p_lo-pp].T) * scale — with the int8
     slices extracted in VMEM from the (hi, lo, exp) operand words.
@@ -859,7 +929,9 @@ def int8_matmul_nt_streaming_dw(a_hi: jax.Array, a_lo: jax.Array,
                                 c_hi: jax.Array, c_lo: jax.Array, *,
                                 num_splits: int, p_lo: int, t: int,
                                 npairs: int, w: int, scale: float,
-                                bm: int = 256, bn: int = 256, bk: int = 512,
+                                bm: Optional[int] = None,
+                                bn: Optional[int] = None,
+                                bk: Optional[int] = None,
                                 interpret: Optional[bool] = None
                                 ) -> tuple[jax.Array, jax.Array]:
     """(c_hi, c_lo) += df32(group product) * scale, streaming-split.

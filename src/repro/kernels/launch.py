@@ -26,8 +26,23 @@ LANE = 128          # last-dim tile multiple (all dtypes)
 SUBLANE_F32 = 8     # second-to-last multiple, 4-byte dtypes
 SUBLANE_I8 = 32     # second-to-last multiple, 1-byte dtypes
 
-VMEM_BYTES = 16 * 2 ** 20   # per-core VMEM (v4/v5 class)
+VMEM_BYTES = 16 * 2 ** 20   # default scoped VMEM of a Mosaic kernel
 SPLIT_LIVE_PLANES = 8       # float temporaries of the in-kernel split
+
+# The GEMM family's blocks when the plan requests none: MXU-aligned
+# 256 x 256 x 512 (int8_matmul_nt, the streaming and fused-CRT kernels).
+GEMM_BLOCKS = (256, 256, 512)
+# The scoped VMEM an epilogue launch declares when its modelled working
+# set passes ``VMEM_BYTES``: a v5e core holds 128 MiB, and what the model
+# leaves below this limit is margin for Mosaic's own scratch.
+EPILOGUE_VMEM_LIMIT = 96 * 2 ** 20
+# (bm, bn) temporaries the model allows the epilogue body. Compiled for a
+# v5e, Mosaic needed 6.25 to 7 such f32 planes beyond the pipeline
+# buffers with the body unrolled over the block, and under one since the
+# body runs in pieces (``int8_gemm.EPILOGUE_K_CHUNK``, ``_ROW_STRIP``).
+# The rest is margin, and it holds the output tile at 1024 x 1024, the
+# largest the chip was measured at.
+EPILOGUE_LIVE_PLANES = 8
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -91,16 +106,90 @@ def grid_for(shape: tuple[int, ...], blocks: tuple[int, ...]) -> tuple[int, ...]
 # on the alignment rules (the planner in ``repro.core.tuning`` mirrors them).
 # ----------------------------------------------------------------------------
 
-def gemm_blocks(m: int, n: int, k: int, bm: int, bn: int,
-                bk: int) -> tuple[int, int, int]:
+def gemm_blocks(m: int, n: int, k: int, bm: Optional[int] = None,
+                bn: Optional[int] = None,
+                bk: Optional[int] = None) -> tuple[int, int, int]:
     """Blocks for the int8 NT GEMM family: (m, k) x (n, k) -> (m, n).
 
-    bm/bn are sublane dims of int8 operand tiles; bn doubles as the lane
-    dim of the int32 (or float, for the epilogue-fused variants) output
-    tile, so the stricter 128 alignment applies to it and to bk.
+    A ``None`` block is no request: it takes ``GEMM_BLOCKS``. bm/bn are
+    sublane dims of int8 operand tiles; bn doubles as the lane dim of
+    the int32 (or float) output tile, so the stricter 128 alignment
+    applies to it and to bk.
     """
+    bm, bn, bk = (d if r is None else r
+                  for r, d in zip((bm, bn, bk), GEMM_BLOCKS))
     return (shrink_block(bm, m, SUBLANE_I8), shrink_block(bn, n, LANE),
             shrink_block(bk, k, LANE))
+
+
+def epilogue_working_set(bm: int, bn: int, bk: int, *, planes: int,
+                         el_bytes: int) -> int:
+    """VMEM bytes of one epilogue-GEMM launch at blocks (bm, bn, bk).
+
+    The int8 A and B tiles and the ``planes`` carried accumulator planes,
+    in and out, all double-buffered by the Pallas pipeline; the (bm, bn)
+    int32 product scratch; and ``EPILOGUE_LIVE_PLANES`` (bm, bn)
+    temporaries of the epilogue body.
+    """
+    operands = 2 * bk * (bm + bn)
+    carried = planes * 2 * 2 * el_bytes * bm * bn
+    scratch = 4 * bm * bn
+    temps = EPILOGUE_LIVE_PLANES * el_bytes * bm * bn
+    return operands + carried + scratch + temps
+
+
+def _halve(block: int, align: int) -> int:
+    """The next power of two below ``block`` (an extent-shrunk block need
+    not be one), floored at ``align``."""
+    return max(align, 1 << ((block - 1).bit_length() - 1))
+
+
+def epilogue_blocks(m: int, n: int, k: int, bm: Optional[int] = None,
+                    bn: Optional[int] = None, bk: Optional[int] = None, *,
+                    planes: int, el_bytes: int) -> tuple[int, int, int]:
+    """Blocks for the epilogue-fused GEMM: the largest output tile, then
+    the largest reduction slab, whose ``epilogue_working_set`` fits
+    ``EPILOGUE_VMEM_LIMIT``.
+
+    Each output tile streams the whole K strip of both slice stacks, so
+    A is read n/bn times and B m/bm times: the output tile sets the
+    operand traffic and, with bk, the number of grid steps. A ``None``
+    block is no request; a number is a request the recipe only shrinks.
+    Blocks start at the request or the aligned extent, and halve to
+    powers of two (the larger of bm and bn first, then bk) until the
+    working set fits, so on power-of-two extents they divide them and
+    ``pad_tail`` copies nothing.
+    """
+    bm_, bn_, bk_ = (align_up(d, a) if r is None else shrink_block(r, d, a)
+                     for r, d, a in ((bm, m, SUBLANE_I8), (bn, n, LANE),
+                                     (bk, k, LANE)))
+
+    def fits(bm, bn, bk):
+        return epilogue_working_set(bm, bn, bk, planes=planes,
+                                    el_bytes=el_bytes) <= EPILOGUE_VMEM_LIMIT
+
+    while not fits(bm_, bn_, min(bk_, LANE)):
+        if bm_ >= bn_ and bm_ > SUBLANE_I8:
+            bm_ = _halve(bm_, SUBLANE_I8)
+        elif bn_ > LANE:
+            bn_ = _halve(bn_, LANE)
+        elif bm_ > SUBLANE_I8:
+            bm_ = _halve(bm_, SUBLANE_I8)
+        else:
+            break
+    while not fits(bm_, bn_, bk_) and bk_ > LANE:
+        bk_ = _halve(bk_, LANE)
+    return bm_, bn_, bk_
+
+
+def epilogue_vmem_limit(bm: int, bn: int, bk: int, *, planes: int,
+                        el_bytes: int) -> Optional[int]:
+    """The scoped VMEM an epilogue launch at these blocks declares:
+    ``None`` (Mosaic's default) while the working set fits
+    ``VMEM_BYTES``, else ``EPILOGUE_VMEM_LIMIT``, the limit
+    ``epilogue_blocks`` fits it to."""
+    ws = epilogue_working_set(bm, bn, bk, planes=planes, el_bytes=el_bytes)
+    return None if ws <= VMEM_BYTES else EPILOGUE_VMEM_LIMIT
 
 
 def int8_tile_blocks(m: int, k: int, bm: int, bk: int) -> tuple[int, int]:
@@ -146,7 +235,8 @@ def _crt_working_set(bm: int, bn: int, bk: int, *, ell: int) -> int:
     return operands + accum + out
 
 
-def crt_blocks(m: int, n: int, k: int, bm: int, bn: int, bk: int, *,
+def crt_blocks(m: int, n: int, k: int, bm: Optional[int],
+               bn: Optional[int], bk: Optional[int], *,
                ell: int, vmem_budget: int = VMEM_BYTES // 2
                ) -> tuple[int, int, int]:
     """Blocks for the fused-CRT residue GEMM: validated against the VMEM
@@ -176,7 +266,8 @@ def crt_blocks(m: int, n: int, k: int, bm: int, bn: int, bk: int, *,
     return bm_, bn_, bk_
 
 
-def streaming_blocks(m: int, n: int, k: int, bm: int, bn: int, bk: int, *,
+def streaming_blocks(m: int, n: int, k: int, bm: Optional[int],
+                     bn: Optional[int], bk: Optional[int], *,
                      num_splits_a: int, num_splits_b: int, el_bytes: int,
                      vmem_budget: int = VMEM_BYTES // 2
                      ) -> tuple[int, int, int]:

@@ -16,7 +16,9 @@ behind a switch:
   running a span costs next to nothing.
 * **Counters** (``count``, ``counters``) count the work as it is issued,
   per process: front-door traces, plans resolved, GEMM launches and the
-  slice pairs those launches cover.
+  slice pairs those launches cover. ``grid_steps`` counts the grid steps
+  of the epilogue GEMM launches, each of which costs the kernel a fixed
+  overhead beside its MXU work.
 """
 from __future__ import annotations
 
@@ -44,9 +46,14 @@ STAGES = (LAYOUT, EXPONENTS, SPLIT, GEMM, SCALE_OUT)
 SCOPES = (MATMUL,) + STAGES
 SPANS = (MATMUL, PLAN)
 COUNTERS = ("matmul_traces", "plans", "gemm_launches", "gemm_pairs")
+# (m/bm) (n/bn) pairs (k/bk), times the batch, summed over the epilogue
+# GEMM launches. Read by ``grid_steps()``, apart from ``counters()``:
+# the chip benchmark's tests hold the detail run's counter deltas to
+# exactly the four keys above.
+GRID_STEPS = "gemm_grid_steps"
 
 _lock = threading.Lock()
-_counts = dict.fromkeys(COUNTERS, 0)
+_counts = dict.fromkeys(COUNTERS + (GRID_STEPS,), 0)
 
 
 def scope(name: str):
@@ -76,21 +83,29 @@ def span(name: str):
 
 def count(name: str, n: int = 1) -> None:
     """Add ``n`` to counter ``name`` (one of ``COUNTERS``)."""
+    if name not in COUNTERS:
+        raise ValueError(f"unknown counter {name!r}; expected one of "
+                         f"{COUNTERS}")
     with _lock:
-        if name not in _counts:
-            raise ValueError(f"unknown counter {name!r}; expected one of "
-                             f"{COUNTERS}")
         _counts[name] += n
 
 
-def gemm_launch(pairs: int, launches: int = 1) -> None:
-    """Count ``launches`` GEMM launches covering ``pairs`` slice pairs."""
+def gemm_launch(pairs: int, launches: int = 1, grid_steps: int = 0) -> None:
+    """Count ``launches`` GEMM launches covering ``pairs`` slice pairs in
+    ``grid_steps`` kernel grid steps."""
     with _lock:
         _counts["gemm_launches"] += launches
         _counts["gemm_pairs"] += pairs
+        _counts[GRID_STEPS] += grid_steps
 
 
 def counters() -> dict:
-    """A snapshot of every counter."""
+    """A snapshot of every counter of ``COUNTERS``."""
     with _lock:
-        return dict(_counts)
+        return {k: _counts[k] for k in COUNTERS}
+
+
+def grid_steps() -> int:
+    """The grid steps the epilogue GEMM launches have issued."""
+    with _lock:
+        return _counts[GRID_STEPS]

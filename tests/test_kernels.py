@@ -8,9 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.splitting import row_exponents, split_int, split_int_dw
+from repro.core.executors import EpilogueExecutor, XlaExecutor
+from repro.core.splitting import (SplitResult, row_exponents, split_int,
+                                  split_int_dw)
+from repro.core.tuning import PipelinePlan
 from repro.core.xmath import DW, df32_from_f64
-from repro.kernels import ref
+from repro.kernels import launch, ref
 from repro.kernels.int8_gemm import (int8_matmul_nt, int8_matmul_nt_batched,
                                      int8_matmul_nt_epilogue_dw,
                                      int8_matmul_nt_epilogue_sw)
@@ -163,6 +166,104 @@ def test_int8_gemm_epilogue_block_shapes(rng):
               for i in range(3))
     want = np.asarray(c) + p_t.astype(np.float64) * scale
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("accum", ["df32", "f64"])
+@pytest.mark.parametrize("batch", [None, 2])
+@pytest.mark.parametrize("m,n,k", [(2048, 2048, 256), (600, 1100, 700)])
+def test_int8_gemm_epilogue_recipe_blocks_match_xla(rng, accum, batch, m, n,
+                                                    k):
+    """With no block requested the epilogue launches take
+    ``epilogue_blocks`` (several output tiles at 2048^2; one padded block
+    at 600 x 1100 x 700), and the epilogue executor stays bitwise the
+    XLA route: df32 (dw kernels) and f64 (sw), 2-D and batch-grid."""
+    s, w = 3, 7
+    lead = (s,) if batch is None else (s, batch)
+    planes, el_bytes = (1, 8) if accum == "f64" else (2, 4)
+    blocks = launch.epilogue_blocks(m, n, k, planes=planes,
+                                    el_bytes=el_bytes)
+    if m == 2048:       # blocks that divide, several output tiles
+        assert all(d % b == 0 for d, b in zip((m, n, k), blocks))
+        assert blocks[0] * blocks[1] < m * n
+    else:               # one block, padded past the extents
+        assert blocks == (608, 1152, 768)
+    sa = SplitResult(jnp.asarray(rng.integers(-64, 65, lead + (m, k)),
+                                 jnp.int8), None, w)
+    sb = SplitResult(jnp.asarray(rng.integers(-64, 65, lead + (n, k)),
+                                 jnp.int8), None, w)
+    shape = lead[1:] + (m, n)
+    e_base = jnp.asarray(rng.integers(-8, 8, shape), jnp.int32)
+    outs = [ex(PipelinePlan(num_splits=s, backend=backend, fusion=fusion,
+                            accum=accum, interpret=True)
+               ).contract(sa, sb, w, e_base, shape)
+            for ex, backend, fusion in ((XlaExecutor, "xla", "none"),
+                                        (EpilogueExecutor, "pallas_fused",
+                                         "epilogue"))]
+    for want, got in zip(*(jax.tree.leaves(o) for o in outs)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# cell shapes (m, n, k): dgemm.square16k, batched.serve4k (batch folded
+# into rows), dgemm.rank256
+CELL_SHAPES = [(16384, 16384, 16384), (4096, 4096, 4096),
+               (16384, 16384, 256)]
+
+
+@pytest.mark.parametrize("m,n,k", CELL_SHAPES)
+def test_epilogue_blocks_fit_and_divide_at_cell_shapes(m, n, k):
+    blocks = launch.epilogue_blocks(m, n, k, planes=2, el_bytes=4)
+    ws = launch.epilogue_working_set(*blocks, planes=2, el_bytes=4)
+    assert ws <= launch.EPILOGUE_VMEM_LIMIT
+    assert all(d % b == 0 for d, b in zip((m, n, k), blocks)), blocks
+    assert blocks[:2] == (1024, 1024)
+    # the launch declares the limit its working set was fitted to
+    assert ws > launch.VMEM_BYTES
+    assert launch.epilogue_vmem_limit(*blocks, planes=2, el_bytes=4) == \
+        launch.EPILOGUE_VMEM_LIMIT
+
+
+def test_epilogue_blocks_shrink_to_small_extents():
+    assert launch.epilogue_blocks(8, 8, 1, planes=2, el_bytes=4) == \
+        (32, 128, 128)
+    assert launch.epilogue_blocks(600, 1100, 700, planes=1, el_bytes=8) == \
+        (608, 1152, 768)
+    assert launch.epilogue_vmem_limit(32, 128, 128, planes=2,
+                                      el_bytes=4) is None
+
+
+def test_epilogue_blocks_only_shrink_a_request():
+    m = n = k = 16384
+    assert launch.epilogue_blocks(m, n, k, 256, 256, 512, planes=2,
+                                  el_bytes=4) == (256, 256, 512)
+    # a request past the VMEM limit halves: the output tile first
+    bm, bn, bk = launch.epilogue_blocks(m, n, k, 4096, 4096, 4096,
+                                        planes=2, el_bytes=4)
+    assert (bm, bn) == (1024, 1024)
+    assert launch.epilogue_working_set(bm, bn, bk, planes=2, el_bytes=4) \
+        <= launch.EPILOGUE_VMEM_LIMIT
+
+
+# The other GEMM families launch what they launched before the epilogue
+# got its own recipe: (shape, gemm_blocks, streaming_blocks at s = 9 and
+# df32 words, crt_blocks at 18 moduli), with no block requested.
+OTHER_FAMILIES = [
+    ((8, 8, 1), (32, 128, 128), (32, 128, 128), (32, 128, 128)),
+    ((100, 130, 530), (128, 256, 512), (128, 256, 512), (128, 256, 512)),
+    ((600, 1100, 700), (256, 256, 512), (256, 256, 256), (256, 256, 512)),
+    ((4096, 4096, 4096), (256, 256, 512), (256, 256, 256), (256, 256, 512)),
+    ((16384, 16384, 256), (256, 256, 256), (256, 256, 256), (256, 256, 256)),
+    ((16384, 16384, 16384), (256, 256, 512), (256, 256, 256),
+     (256, 256, 512)),
+]
+
+
+@pytest.mark.parametrize("shape,gemm,streaming,crt", OTHER_FAMILIES)
+def test_other_gemm_families_keep_their_blocks(shape, gemm, streaming, crt):
+    assert launch.gemm_blocks(*shape) == gemm
+    assert launch.gemm_blocks(*shape, *launch.GEMM_BLOCKS) == gemm
+    assert launch.streaming_blocks(*shape, None, None, None, num_splits_a=9,
+                                   num_splits_b=9, el_bytes=4) == streaming
+    assert launch.crt_blocks(*shape, None, None, None, ell=18) == crt
 
 
 def test_int8_gemm_jit_composes(rng):

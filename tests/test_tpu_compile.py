@@ -90,6 +90,39 @@ def test_kernel_lowers_to_mosaic(shape, name):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("m,n,k", [(16384, 16384, 16384), (4096, 4096, 4096),
+                                   (16384, 16384, 256)])
+def test_epilogue_lowers_at_cell_blocks(shape, m, n, k):
+    """The epilogue GEMM at the blocks ``epilogue_blocks`` picks for each
+    chip benchmark cell's shape (square16k, serve4k with its batch folded
+    into rows, rank256): Mosaic takes the block layout within the scoped
+    VMEM limit the launch declares, and the kernel's code, which sits in
+    HBM once per launch, stays within a few times that of 256 x 256 x 512
+    blocks (the body runs in pieces; unrolled it was 15 times as large)."""
+    from repro.kernels import launch, ops
+    blocks = launch.epilogue_blocks(m, n, k, planes=2, el_bytes=4)
+    limit = launch.epilogue_vmem_limit(*blocks, planes=2, el_bytes=4)
+    assert limit == launch.EPILOGUE_VMEM_LIMIT
+    i8, f32 = jnp.int8, jnp.float32
+    args = (shape((S, m, k), i8), shape((S, n, k), i8), shape((m, n), f32),
+            shape((m, n), f32))
+
+    def lower(**blocks):
+        return jax.jit(lambda a, b, ch, cl: ops.int8_matmul_nt_epilogue_dw(
+            a, b, ch, cl, p_lo=0, t=S - 1, npairs=S, scale=2.0 ** (-10 * W),
+            interpret=False, **blocks)).lower(*args)
+
+    lowered = lower()
+    # the declared limit rides in the kernel call's backend config
+    assert f"\\22size\\22: {limit}" in lowered.as_text()
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    small = lower(bm=256, bn=256, bk=512).compile()
+    code = [c.memory_analysis().generated_code_size_in_bytes
+            for c in (compiled, small)]
+    assert code[0] <= 4 * code[1], code
+
+
 def _program_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes +

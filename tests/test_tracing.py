@@ -6,6 +6,7 @@ else: the program lowered without them is the same text once the
 metadata is stripped. The counters count what the program issued.
 """
 import contextlib
+import math
 import re
 
 import jax
@@ -15,6 +16,7 @@ import pytest
 import repro
 from repro import tracing
 from repro.core.xmath import DW
+from repro.kernels import launch
 
 SPEC = "ozaki-fp64x9/pallas_fused+epilogue"
 ROUTES = {"2d": ((48, 256), (256, 40)), "batch_fold": ((2, 24, 256), (256, 40))}
@@ -107,6 +109,35 @@ def test_counters_follow_the_launch_schedule():
         assert after["gemm_pairs"] - before["gemm_pairs"] == pairs
 
 
+# operand shapes of the chip benchmark's cells: dgemm.square16k,
+# batched.serve4k (the batch folds into rows), dgemm.rank256
+CELLS = {"square16k": ((16384, 16384), (16384, 16384)),
+         "serve4k": ((32, 128, 4096), (4096, 4096)),
+         "rank256": ((16384, 256), (256, 16384))}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_grid_steps_at_cell_shapes(cell):
+    """One front-door trace at a cell's shape issues the grid steps of
+    ``epilogue_blocks``: 45 pairs over (m/bm)(n/bn)(k/bk) tiles, at least
+    4x fewer than the 256 x 256 x 512 blocks the launches took before
+    (184,320 steps at rank256). Traced with ``jax.eval_shape``: nothing
+    runs."""
+    a, b = CELLS[cell]
+    m, k, n = math.prod(a[:-1]), a[-1], b[-1]
+    before = tracing.grid_steps()
+    jax.eval_shape(lambda ah, al, bh, bl: repro.matmul(
+        DW(ah, al), DW(bh, bl), precision=SPEC),
+        *[jax.ShapeDtypeStruct(d, jnp.float32) for d in (a, a, b, b)])
+    steps = tracing.grid_steps() - before
+    bm, bn, bk = launch.epilogue_blocks(m, n, k, planes=2, el_bytes=4)
+    assert steps == 45 * (m // bm) * (n // bn) * (k // bk)
+    old_steps = 45 * (m // 256) * (n // 256) * -(-k // 512)
+    assert old_steps >= 4 * steps
+    if cell == "rank256":
+        assert old_steps == 184_320
+
+
 def test_eager_calls_count_each_call(rng):
     a = jnp.asarray(rng.standard_normal((8, 32)))
     b = jnp.asarray(rng.standard_normal((32, 8)))
@@ -125,3 +156,6 @@ def test_unknown_names_are_refused():
         tracing.span("call")
     with pytest.raises(ValueError):
         tracing.count("launches")
+    # grid steps are counted with the launches, and read apart
+    with pytest.raises(ValueError):
+        tracing.count(tracing.GRID_STEPS)
